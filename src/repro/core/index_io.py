@@ -46,6 +46,7 @@ from repro.core.store import (
     StoreBackend,
     stream_backend_items,
 )
+from repro.core.trace import span
 
 MANIFEST_NAME = "manifest.json"
 SA_FILE = "suffix_array.npy"
@@ -102,6 +103,7 @@ def save_index(
     stats: Optional[Dict[str, Any]] = None,
     corpus_ref: Optional[str] = None,
     chunk_items: int = 0,
+    timers: Optional[Dict[str, float]] = None,
 ) -> str:
     """Write a complete index directory; returns the manifest path.
 
@@ -110,7 +112,16 @@ def save_index(
     already placed inside ``index_dir``).  None serializes the backend's
     items into ``{index_dir}/corpus.sachunk``.  Arrays already memmapped at
     their target paths (the streaming sink's output) are not rewritten.
+    The save is the span ``index.save``; with ``timers``, its seconds are
+    added to ``timers["t_save_s"]``.
     """
+    with span("index.save", timers, "t_save_s"):
+        return _write_index(index_dir, cfg, backend, sa, lcp, stats,
+                            corpus_ref, chunk_items)
+
+
+def _write_index(index_dir, cfg, backend, sa, lcp, stats, corpus_ref,
+                 chunk_items) -> str:
     os.makedirs(index_dir, exist_ok=True)
     _write_array(sa, os.path.join(index_dir, SA_FILE))
     if lcp is not None:

@@ -46,6 +46,7 @@ from repro.core.distributed import (
     sample_splitters,
 )
 from repro.core.store import StoreSpec, mget_window, token_bytes
+from repro.core.trace import span
 from repro.core.types import (
     KEY_SENTINEL,
     WORD_BITS,
@@ -84,6 +85,7 @@ def _suffix_exhausted(ih, il, depth, *, text_mode, text_len, uniform_len,
     return rem <= depth * k
 
 
+@jax.named_scope("refine")
 def _refine_tie_groups(g, ih, il, exhausted, *, store_local, spec, cfg,
                        analytic, text_mode, text_len, uniform_len,
                        stride_bits, hard_cap):
@@ -177,6 +179,25 @@ def _refine_tie_groups(g, ih, il, exhausted, *, store_local, spec, cfg,
 def _map_phase(reads_l, lengths_l, halo_l, *, cfg, rows_per_shard, stride_bits,
                text_mode, text_len):
     """Map + sample + bucket (shared by the histogram pre-pass and main run)."""
+    rec, valid0 = _map_records(
+        reads_l, lengths_l, halo_l, cfg=cfg, rows_per_shard=rows_per_shard,
+        stride_bits=stride_bits, text_mode=text_mode, text_len=text_len,
+    )
+    with jax.named_scope("sample"):
+        s_hi, s_lo = sample_splitters(rec[0], rec[1], cfg.samples_per_shard,
+                                      AXIS)
+        bucket = lex_bucket(rec[0], rec[1], s_hi, s_lo)
+        # invalid padding records go to a local dump bucket, never shipped
+        nb = lax.axis_size(AXIS)
+        bucket = jnp.where(valid0.reshape(-1), bucket, jnp.int32(nb))
+    return rec, valid0, bucket
+
+
+@jax.named_scope("map")
+def _map_records(reads_l, lengths_l, halo_l, *, cfg, rows_per_shard,
+                 stride_bits, text_mode, text_len):
+    """Map: each local suffix's record (packed K-token key, global index)
+    and whether it is a real suffix."""
     me = lax.axis_index(AXIS)
     if text_mode:
         flat = jnp.concatenate([reads_l.reshape(-1), halo_l.reshape(-1)])
@@ -206,12 +227,7 @@ def _map_phase(reads_l, lengths_l, halo_l, *, cfg, rows_per_shard, stride_bits,
             stride_bits=stride_bits,
         )
         rec = jnp.where(valid0[None, :], rec, KEY_SENTINEL)
-    s_hi, s_lo = sample_splitters(rec[0], rec[1], cfg.samples_per_shard, AXIS)
-    bucket = lex_bucket(rec[0], rec[1], s_hi, s_lo)
-    # invalid padding records go to a local dump bucket, never shipped
-    nb = lax.axis_size(AXIS)
-    bucket = jnp.where(valid0.reshape(-1), bucket, jnp.int32(nb))
-    return rec, valid0, bucket
+    return rec, valid0
 
 
 def _hist_fn(reads_l, lengths_l, halo_l, *, cfg, num_shards, rows_per_shard,
@@ -253,15 +269,18 @@ def _device_fn(
     n_valid_local = jnp.sum(valid0).astype(jnp.int32)
 
     # ---- Shuffle: the 16-byte-record all_to_all ----------------------
-    buf, slot, _ = bucket_scatter(rec, bucket, d + 1, shuffle_cap, KEY_SENTINEL)
+    with jax.named_scope("shuffle"):
+        buf, slot, _ = bucket_scatter(rec, bucket, d + 1, shuffle_cap,
+                                      KEY_SENTINEL)
+        recv = exchange(buf[:, :d], AXIS).reshape(4, d * shuffle_cap)
     drop_shuffle = jnp.sum(
         valid0.reshape(-1) & (slot >= d * shuffle_cap)
     ).astype(jnp.int32)
-    recv = exchange(buf[:, :d], AXIS).reshape(4, d * shuffle_cap)
 
     # ---- Reduce: initial sort ----------------------------------------
     kh, kl, ih, il = recv
-    kh, kl, ih, il = lex_sort((kh, kl, ih, il), num_keys=4)
+    with jax.named_scope("sort"):
+        kh, kl, ih, il = lex_sort((kh, kl, ih, il), num_keys=4)
     validr = ih != KEY_SENTINEL
 
     eq = jnp.concatenate(
@@ -490,25 +509,30 @@ def build_suffix_array(
     """
     mesh = _flat_mesh(mesh)
     d = mesh.devices.size
-    info = plan(np.shape(corpus), cfg, d, lengths)
-    data, lens, halo = _shard_inputs(corpus, lengths, cfg, d, info)
-    sharding = NamedSharding(mesh, P(AXIS))
-    data = jax.device_put(data, sharding)
-    lens = jax.device_put(lens, sharding)
-    halo = jax.device_put(halo, sharding)
-
-    shuffle_cap = None
-    if cfg.adaptive:
-        shuffle_cap = _exact_shuffle_cap(
-            np.shape(corpus), cfg, mesh, data, lens, halo
+    timers = {"t_device_s": 0.0}
+    with span("build.stage"):
+        info = plan(np.shape(corpus), cfg, d, lengths)
+        data, lens, halo = _shard_inputs(corpus, lengths, cfg, d, info)
+        sharding = NamedSharding(mesh, P(AXIS))
+        data = jax.device_put(data, sharding)
+        lens = jax.device_put(lens, sharding)
+        halo = jax.device_put(halo, sharding)
+    # the device's share: histogram pre-pass, pipeline, outputs on the host
+    with span("build.device", timers, "t_device_s"):
+        shuffle_cap = None
+        if cfg.adaptive:
+            shuffle_cap = _exact_shuffle_cap(
+                np.shape(corpus), cfg, mesh, data, lens, halo
+            )
+        jitted, info = make_pipeline(
+            np.shape(corpus), cfg, mesh, lengths, shuffle_cap=shuffle_cap
         )
-    jitted, info = make_pipeline(
-        np.shape(corpus), cfg, mesh, lengths, shuffle_cap=shuffle_cap
-    )
-    ih, il, statmat = jitted(data, lens, halo)
-    return _finalize(
-        np.asarray(ih), np.asarray(il), np.asarray(statmat), corpus, cfg
-    )
+        ih, il, statmat = jitted(data, lens, halo)
+        ih, il, statmat = np.asarray(ih), np.asarray(il), np.asarray(statmat)
+    with span("build.finalize"):
+        res = _finalize(ih, il, statmat, corpus, cfg)
+    res.stats.update(timers)
+    return res
 
 
 def _finalize(ih, il, statmat, corpus, cfg: SAConfig) -> SAResult:
@@ -610,7 +634,9 @@ def _refiner_fn(
 
     # depth-0 windows for the local slice (remote fetch: the indexes are
     # arbitrary, their tokens live on whichever device owns them)
-    win, exh0, ok, fs0 = mget_window(store_local, row, off, valid0, spec, cfg)
+    with jax.named_scope("sample"):
+        win, exh0, ok, fs0 = mget_window(store_local, row, off, valid0, spec,
+                                         cfg)
     words = win if cfg.server_pack else encoding.pack_columns(win, cfg)
     kh = jnp.where(valid0, words[0], KEY_SENTINEL)
     kl = jnp.where(valid0, words[1], KEY_SENTINEL)
@@ -623,13 +649,17 @@ def _refiner_fn(
          jnp.where(valid0, idx_lo, KEY_SENTINEL),
          exh0.astype(jnp.int32)],
     )
-    s_hi, s_lo = sample_splitters(kh, kl, cfg.samples_per_shard, AXIS)
-    bucket = jnp.where(valid0, lex_bucket(kh, kl, s_hi, s_lo), jnp.int32(d))
-    buf, slot, _ = bucket_scatter(rec, bucket, d + 1, cap, KEY_SENTINEL)
+    with jax.named_scope("sample"):
+        s_hi, s_lo = sample_splitters(kh, kl, cfg.samples_per_shard, AXIS)
+        bucket = jnp.where(valid0, lex_bucket(kh, kl, s_hi, s_lo),
+                           jnp.int32(d))
+    with jax.named_scope("shuffle"):
+        buf, slot, _ = bucket_scatter(rec, bucket, d + 1, cap, KEY_SENTINEL)
+        recv = exchange(buf[:, :d], AXIS).reshape(5, d * cap)
     drop = jnp.sum(valid0 & (slot >= d * cap)).astype(jnp.int32)
-    recv = exchange(buf[:, :d], AXIS).reshape(5, d * cap)
     kh, kl, ih, il, exh_i = recv
-    kh, kl, ih, il, exh_i = lex_sort((kh, kl, ih, il, exh_i), num_keys=4)
+    with jax.named_scope("sort"):
+        kh, kl, ih, il, exh_i = lex_sort((kh, kl, ih, il, exh_i), num_keys=4)
     validr = ih != KEY_SENTINEL
 
     eq = jnp.concatenate(

@@ -35,6 +35,7 @@ from repro.core.integrity import (
     DEFAULT_RETRYABLE,
     TransientStoreError,
 )
+from repro.core.trace import span
 from repro.core.types import WORD_BITS, KEY_SENTINEL
 
 
@@ -460,6 +461,8 @@ class ChunkedFileBackend(StoreBackend):
         self._cache: "OrderedDict[int, np.ndarray]" = OrderedDict()
         self._resident = 0
         self.evictions = 0
+        # seconds in the miss path (eviction + read + crc check)
+        self.timers = {"t_chunk_read_s": 0.0}
 
     @property
     def resident_bytes(self) -> int:
@@ -477,12 +480,14 @@ class ChunkedFileBackend(StoreBackend):
             self.cache_hits += 1
             return chunk
         self.cache_misses += 1
-        incoming = self._full_chunk_bytes  # upper bound (tail chunks shorter)
-        while self._cache and self._resident + incoming > self.cache_budget_bytes:
-            _, old = self._cache.popitem(last=False)
-            self._resident -= old.nbytes
-            self.evictions += 1
-        chunk = self._reader.read_chunk(ci, halo=self.k if self.text_mode else 0)
+        with span("store.chunk_read", self.timers, "t_chunk_read_s"):
+            incoming = self._full_chunk_bytes  # upper bound (tail chunks shorter)
+            while self._cache and self._resident + incoming > self.cache_budget_bytes:
+                _, old = self._cache.popitem(last=False)
+                self._resident -= old.nbytes
+                self.evictions += 1
+            chunk = self._reader.read_chunk(
+                ci, halo=self.k if self.text_mode else 0)
         self._cache[ci] = chunk
         self._resident += chunk.nbytes
         return chunk
@@ -752,6 +757,9 @@ class CorpusStore:
         # because staged blocks are transient build input, not merge traffic)
         self.staged_items = 0
         self.staged_bytes = 0
+        # seconds in the main thread's window fetches (fetch_windows,
+        # fetch_keys)
+        self.timers = {"t_fetch_s": 0.0}
         self._note_resident()
 
     @property
@@ -823,17 +831,20 @@ class CorpusStore:
         m = gidx.shape[0]
         depth = np.broadcast_to(np.asarray(depth, np.int64), (m,))
         out = np.zeros((m, self.k), np.int32)
-        for lo in range(0, m, self.request_capacity):
-            hi = min(lo + self.request_capacity, m)
-            out[lo:hi] = self._gather(gidx[lo:hi], depth[lo:hi])
-            self.rounds += 1
-            self.requests += hi - lo
-            self.request_bytes += (hi - lo) * self.index_bytes
-            self.response_bytes += (hi - lo) * self.k * self.token_bytes
+        with span("store.fetch", self.timers, "t_fetch_s"):
+            for lo in range(0, m, self.request_capacity):
+                hi = min(lo + self.request_capacity, m)
+                out[lo:hi] = self._gather(gidx[lo:hi], depth[lo:hi])
+                self.rounds += 1
+                self.requests += hi - lo
+                self.request_bytes += (hi - lo) * self.index_bytes
+                self.response_bytes += (hi - lo) * self.k * self.token_bytes
         self.peak_windows = max(self.peak_windows, m)
         return out
 
-    def gather_keys(self, gidx: np.ndarray, depth) -> Tuple[np.ndarray, np.ndarray]:
+    def gather_keys(self, gidx: np.ndarray, depth,
+                    timers: Optional[dict] = None
+                    ) -> Tuple[np.ndarray, np.ndarray]:
         """The backend half of :meth:`fetch_keys`: capacity-chunked backend
         gathers + key packing, **no counter or residency mutation**.
 
@@ -842,15 +853,19 @@ class CorpusStore:
         (one ``gather`` per capacity chunk) is identical to the synchronous
         path, but ``FetchStats`` accounting stays untouched — the collector
         applies it on the main thread via :meth:`note_fetched` at the
-        hand-off (salint SAL010).
+        hand-off (salint SAL010).  The span ``store.fetch`` adds its seconds
+        to ``timers["t_fetch_s"]`` only when the caller passes its timers:
+        the main thread's fetches do, the prefetch on the worker does not
+        (the main thread's wait for it is the merge's collect).
         """
         m = gidx.shape[0]
         depth = np.broadcast_to(np.asarray(depth, np.int64), (m,))
         win = np.zeros((m, self.k), np.int32)
-        for lo in range(0, m, self.request_capacity):
-            hi = min(lo + self.request_capacity, m)
-            win[lo:hi] = self.backend.gather(
-                np.asarray(gidx[lo:hi], np.int64), depth[lo:hi])
+        with span("store.fetch", timers, "t_fetch_s"):
+            for lo in range(0, m, self.request_capacity):
+                hi = min(lo + self.request_capacity, m)
+                win[lo:hi] = self.backend.gather(
+                    np.asarray(gidx[lo:hi], np.int64), depth[lo:hi])
         return pack_keys_np(win, self.cfg), (win == 0).any(axis=1)
 
     def note_fetched(self, m: int) -> None:
@@ -879,7 +894,7 @@ class CorpusStore:
         :meth:`gather_keys` + :meth:`note_fetched`, so the pipelined refill
         prefetch and this path account identically by construction.
         """
-        keys, ended = self.gather_keys(gidx, depth)
+        keys, ended = self.gather_keys(gidx, depth, self.timers)
         self.note_fetched(gidx.shape[0])
         return keys, ended
 

@@ -58,7 +58,6 @@ import math
 import os
 import shutil
 import tempfile
-import time
 import uuid
 import warnings
 from dataclasses import dataclass
@@ -90,6 +89,7 @@ from repro.core.store import (
     backend_fingerprint,
     materialize_backend,
 )
+from repro.core.trace import span
 from repro.core.types import WORD_BITS, WORD_MOD, Footprint, SAResult
 
 
@@ -1042,6 +1042,7 @@ def _merge_path_runs(
     refiner: Optional[DeviceRefiner] = None,
     frontier: Optional[_MergeFrontier] = None,
     executor: Optional[PipelineExecutor] = None,
+    timers: Optional[dict] = None,
 ) -> int:
     """Merge exactly-sorted runs by merge-path tiles; emit in final order.
 
@@ -1057,6 +1058,10 @@ def _merge_path_runs(
     All candidates ranked below every partially-buffered run's last buffered
     member are emitted at once (the merge-path safety horizon), so a tile
     usually drains far more than ``tile`` suffixes per round trip.
+
+    Each phase of a tile is a span (``merge.refill``, ``merge.escalate``,
+    ``merge.rank``, ``merge.collect``, ``merge.emit``) whose seconds add to
+    ``timers["t_merge_<phase>_s"]`` when ``timers`` is given.
 
     Returns the peak candidate count (the merge's record footprint).
     """
@@ -1091,84 +1096,86 @@ def _merge_path_runs(
         # ---- refill: one batched store round for every run's new heads
         # (heads already prefetched into a tile's pending buffer are served
         # from there; only the remainder touches the store) ----
-        pipeline_point("merge:refill")
-        needs = [t.need(tile) for t in tiles]
-        flat = np.concatenate(needs)
-        keys = ended = None
-        if flat.size:
-            keys, ended = store.fetch_keys(flat, 0)
-        off = 0
-        empty_k = np.zeros((0, kw), np.int32)
-        empty_e = np.zeros((0,), bool)
-        for t, n in zip(tiles, needs, strict=True):
-            if n.size:
-                t.admit(keys[off : off + n.size], ended[off : off + n.size],
-                        tile)
-            else:
-                t.admit(empty_k, empty_e, tile)
-            off += n.size
-        _account()  # register the refill before escalation fetches, so
-        # LRU-loading rounds see the full frontier in peak_resident
+        with span("merge.refill", timers, "t_merge_refill_s"):
+            pipeline_point("merge:refill")
+            needs = [t.need(tile) for t in tiles]
+            flat = np.concatenate(needs)
+            keys = ended = None
+            if flat.size:
+                keys, ended = store.fetch_keys(flat, 0)
+            off = 0
+            empty_k = np.zeros((0, kw), np.int32)
+            empty_e = np.zeros((0,), bool)
+            for t, n in zip(tiles, needs, strict=True):
+                if n.size:
+                    t.admit(keys[off : off + n.size], ended[off : off + n.size],
+                            tile)
+                else:
+                    t.admit(empty_k, empty_e, tile)
+                off += n.size
+            _account()  # register the refill before escalation fetches, so
+            # LRU-loading rounds see the full frontier in peak_resident
         live = [t for t in tiles if t.buffered]
         cand_gidx = np.concatenate([t.gidx for t in live])
         c = cand_gidx.shape[0]
         peak_candidates = max(peak_candidates, c)
 
         # ---- escalate ties: whole groups per round, batched fetches -------
-        level = 1
-        width = max(t.words.shape[1] for t in live) // kw
-        g = None
-        tie_col = None
-        while True:
-            for t in live:
-                t.widen(max(level, width))
-            cand_words = np.concatenate([t.words for t in live])
-            cand_levels = np.concatenate([t.levels for t in live])
-            cand_ended = np.concatenate([t.ended for t in live])
-            lo = (level - 1) * kw
-            g = _group_ids(g, cand_words[:, lo : lo + kw])
-            sizes = np.bincount(g)
-            open_grp = np.zeros(sizes.shape[0], bool)
-            np.logical_or.at(open_grp, g, ~cand_ended)
-            amb = (sizes[g] >= 2) & open_grp[g]
-            if not amb.any():
-                break
-            if refiner is not None:
-                # one device refinement resolves every tie group at once:
-                # a member's position in the refined order is decisive
-                # within its group and never consulted across groups.
-                members = np.flatnonzero(amb)
-                order = refiner.refine(cand_gidx[members])
-                # vectorized rank lookup: member i's tie word = its position
-                # in the refined order (no per-suffix host loop)
-                so = np.argsort(order)
-                tie_col = np.zeros(c, np.int32)
-                tie_col[members] = so[
-                    np.searchsorted(order[so], cand_gidx[members])
-                ].astype(np.int32)
-                break
-            if level >= max_levels:
-                raise RuntimeError("merge-path escalation overran the "
-                                   "window bound")
-            # fetch the next window level for unfinished members of open
-            # groups (finished members' deeper words are genuine zeros)
-            fetch = np.flatnonzero(amb & ~cand_ended & (cand_levels <= level))
-            if fetch.size:
-                keys, ended = store.fetch_keys(cand_gidx[fetch], level)
-                bounds = np.cumsum([0, *(t.buffered for t in live)])
-                t_of = np.searchsorted(bounds, fetch, side="right") - 1
-                for ti, t in enumerate(live):
-                    sel = fetch[t_of == ti]
-                    if not sel.size:
-                        continue
-                    local = sel - bounds[ti]
-                    t.widen(level + 1)
-                    t.words[local, level * kw : (level + 1) * kw] = (
-                        keys[t_of == ti])
-                    t.levels[local] = level + 1
-                    t.ended[local] |= ended[t_of == ti]
-            level += 1
-        _account()
+        with span("merge.escalate", timers, "t_merge_escalate_s"):
+            level = 1
+            width = max(t.words.shape[1] for t in live) // kw
+            g = None
+            tie_col = None
+            while True:
+                for t in live:
+                    t.widen(max(level, width))
+                cand_words = np.concatenate([t.words for t in live])
+                cand_levels = np.concatenate([t.levels for t in live])
+                cand_ended = np.concatenate([t.ended for t in live])
+                lo = (level - 1) * kw
+                g = _group_ids(g, cand_words[:, lo : lo + kw])
+                sizes = np.bincount(g)
+                open_grp = np.zeros(sizes.shape[0], bool)
+                np.logical_or.at(open_grp, g, ~cand_ended)
+                amb = (sizes[g] >= 2) & open_grp[g]
+                if not amb.any():
+                    break
+                if refiner is not None:
+                    # one device refinement resolves every tie group at once:
+                    # a member's position in the refined order is decisive
+                    # within its group and never consulted across groups.
+                    members = np.flatnonzero(amb)
+                    order = refiner.refine(cand_gidx[members])
+                    # vectorized rank lookup: member i's tie word = its position
+                    # in the refined order (no per-suffix host loop)
+                    so = np.argsort(order)
+                    tie_col = np.zeros(c, np.int32)
+                    tie_col[members] = so[
+                        np.searchsorted(order[so], cand_gidx[members])
+                    ].astype(np.int32)
+                    break
+                if level >= max_levels:
+                    raise RuntimeError("merge-path escalation overran the "
+                                       "window bound")
+                # fetch the next window level for unfinished members of open
+                # groups (finished members' deeper words are genuine zeros)
+                fetch = np.flatnonzero(amb & ~cand_ended & (cand_levels <= level))
+                if fetch.size:
+                    keys, ended = store.fetch_keys(cand_gidx[fetch], level)
+                    bounds = np.cumsum([0, *(t.buffered for t in live)])
+                    t_of = np.searchsorted(bounds, fetch, side="right") - 1
+                    for ti, t in enumerate(live):
+                        sel = fetch[t_of == ti]
+                        if not sel.size:
+                            continue
+                        local = sel - bounds[ti]
+                        t.widen(level + 1)
+                        t.words[local, level * kw : (level + 1) * kw] = (
+                            keys[t_of == ti])
+                        t.levels[local] = level + 1
+                        t.ended[local] |= ended[t_of == ti]
+                level += 1
+            _account()
 
         # ---- prefetch the next refill while this tile ranks ---------------
         # The store is quiescent during ranking (the Pallas kernel runs on
@@ -1188,47 +1195,50 @@ def _merge_path_runs(
                 pf_task = executor.submit(store.gather_keys, pf_flat, 0)
 
         # ---- rank the tile: merge-path diagonal ranks in one shot ---------
-        pipeline_point("merge:rank")
-        cand_words = np.concatenate([t.words for t in live])
-        if tie_col is not None:
-            cand_words = np.concatenate([cand_words, tie_col[:, None]], axis=1)
-        if use_pallas:
-            from repro.kernels import ops as kops
+        with span("merge.rank", timers, "t_merge_rank_s"):
+            pipeline_point("merge:rank")
+            cand_words = np.concatenate([t.words for t in live])
+            if tie_col is not None:
+                cand_words = np.concatenate([cand_words, tie_col[:, None]], axis=1)
+            if use_pallas:
+                from repro.kernels import ops as kops
 
-            idx_hi = (cand_gidx >> WORD_BITS).astype(np.int32)
-            idx_lo = (cand_gidx & (WORD_MOD - 1)).astype(np.int32)
-            keys_full = np.concatenate(
-                [cand_words, idx_hi[:, None], idx_lo[:, None]], axis=1)
-            ranks = kops.merge_path_ranks(keys_full).astype(np.int64)
-        else:
-            ranks = store.rank_windows(cand_words, cand_gidx)
+                idx_hi = (cand_gidx >> WORD_BITS).astype(np.int32)
+                idx_lo = (cand_gidx & (WORD_MOD - 1)).astype(np.int32)
+                keys_full = np.concatenate(
+                    [cand_words, idx_hi[:, None], idx_lo[:, None]], axis=1)
+                ranks = kops.merge_path_ranks(keys_full).astype(np.int64)
+            else:
+                ranks = store.rank_windows(cand_words, cand_gidx)
 
         # ---- collect the prefetched refill (store is ours again) ----------
         if pf_task is not None:
-            pipeline_point("merge:collect")
-            pf_keys, pf_ended = pf_task.result()
-            store.note_fetched(pf_keys.shape[0])  # main-thread accounting
-            off = 0
-            for t, n in zip(tiles, pf_needs, strict=True):
-                t.admit_pending(pf_keys[off : off + n.size],
-                                pf_ended[off : off + n.size])
-                off += n.size
-            _account()
+            with span("merge.collect", timers, "t_merge_collect_s"):
+                pipeline_point("merge:collect")
+                pf_keys, pf_ended = pf_task.result()
+                store.note_fetched(pf_keys.shape[0])  # main-thread accounting
+                off = 0
+                for t, n in zip(tiles, pf_needs, strict=True):
+                    t.admit_pending(pf_keys[off : off + n.size],
+                                    pf_ended[off : off + n.size])
+                    off += n.size
+                _account()
 
         # ---- emit everything below the safety horizon ---------------------
-        pipeline_point("merge:emit")
-        bounds = np.cumsum([0, *(t.buffered for t in live)])
-        emit_cnt = c
-        for ti, t in enumerate(live):
-            if t.remaining > t.buffered:  # partially buffered run
-                emit_cnt = min(emit_cnt, int(ranks[bounds[ti + 1] - 1]) + 1)
-        emitted = np.empty(emit_cnt, np.int64)
-        take = ranks < emit_cnt
-        emitted[ranks[take]] = cand_gidx[take]
-        sink.append(emitted)
-        for ti, t in enumerate(live):
-            t.consume(int(np.count_nonzero(take[bounds[ti] : bounds[ti + 1]])))
-        _account()
+        with span("merge.emit", timers, "t_merge_emit_s"):
+            pipeline_point("merge:emit")
+            bounds = np.cumsum([0, *(t.buffered for t in live)])
+            emit_cnt = c
+            for ti, t in enumerate(live):
+                if t.remaining > t.buffered:  # partially buffered run
+                    emit_cnt = min(emit_cnt, int(ranks[bounds[ti + 1] - 1]) + 1)
+            emitted = np.empty(emit_cnt, np.int64)
+            take = ranks < emit_cnt
+            emitted[ranks[take]] = cand_gidx[take]
+            sink.append(emitted)
+            for ti, t in enumerate(live):
+                t.consume(int(np.count_nonzero(take[bounds[ti] : bounds[ti + 1]])))
+            _account()
     store.add_frontier(-registered)
     return peak_candidates
 
@@ -1275,6 +1285,13 @@ def _split_boundary_risk(
 # ---------------------------------------------------------------------------
 # the out-of-core build
 # ---------------------------------------------------------------------------
+
+# build stats of the out-of-core phases' spans, in seconds: staging, block
+# builds (the device pipeline's share of them), the merge, its phases and
+# the store's window fetches on the main thread (all of them in the merge)
+_TIMERS = ("t_stage_s", "t_build_s", "t_device_s", "t_merge_s",
+           "t_merge_refill_s", "t_merge_escalate_s", "t_merge_rank_s",
+           "t_merge_collect_s", "t_merge_emit_s", "t_fetch_s")
 
 
 def build_suffix_array_superblock(
@@ -1326,7 +1343,9 @@ def build_suffix_array_superblock(
     owns_backend = True
     ok = False
     try:
-        backend = _resolve_backend(corpus, cfg, sb, scratch)
+        corpus_timer: dict = {}  # the corpus written to the chunked format
+        with span("build.corpus", corpus_timer, "t_corpus_s"):
+            backend = _resolve_backend(corpus, cfg, sb, scratch)
         owns_backend = backend is not corpus  # decided before any wrapping
         if sb.store_retries > 0:
             backend = RetryingBackend(backend, retries=sb.store_retries,
@@ -1337,6 +1356,7 @@ def build_suffix_array_superblock(
             backend, lengths, cfg, sb, mesh, scratch,
             original_corpus=corpus,
         )
+        res.stats.update(corpus_timer)
         ok = True
         return res
     finally:
@@ -1424,7 +1444,8 @@ def _build_superblock_phases(
         # LCP is recomputed post-hoc from the finished SA, and the index
         # directory (when asked for) is written wholesale.
         if sb.emit_lcp and res.lcp is None:
-            res.lcp = lcp_from_sa(store, res.suffix_array)
+            with span("build.lcp", res.stats, "t_lcp_s"):
+                res.lcp = lcp_from_sa(store, res.suffix_array)
             res.stats["emit_lcp"] = True
         if sb.write_manifest:
             _write_index_manifest(res, backend, cfg, sb, scratch)
@@ -1574,7 +1595,8 @@ def _build_superblock_phases(
             jr.append(rec)  # fsync'd: the unit of recovery
             pending_journal.pop(0)
 
-    t_stage = t_build = 0.0
+    # phase seconds, accumulated by the phases' spans
+    timers = dict.fromkeys(_TIMERS, 0.0)
     for i, (lo, hi) in enumerate(blocks):
         pre = resumed.get(i)
         if pre is not None:
@@ -1593,71 +1615,157 @@ def _build_superblock_phases(
                                   rec["stats"]["num_suffixes"])
             journal_hits += 1
             continue
-        t0 = time.perf_counter()
-        entry = prefetched.pop(i, None)
-        if entry is not None:
-            task, reg = entry
-            pipeline_point("stage:collect")
-            block = task.result()  # staged in the background, not cached
-            store.note_staged(lo, hi, block.nbytes)
-            if reg:
-                store.add_frontier(-reg)
-                pf_registered -= reg
-        else:
-            block = store.stage_items(lo, hi)  # transient staging, not cached
-        _submit_stages(i + 1)  # overlap: next blocks stage while this builds
-        t_stage += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        pipeline_point("build:block")
-        if plan.text_mode:
-            res = build_suffix_array(block, cfg=cfg, mesh=mesh)
-            sa_b = res.suffix_array + lo
-        else:
-            lens_b = None if lengths is None else np.asarray(lengths)[lo:hi]
-            res = build_suffix_array(block, lengths=lens_b, cfg=cfg, mesh=mesh)
-            sa_b = res.suffix_array + (np.int64(lo) << plan.stride_bits)
-        run = keep_run(sa_b)
-        local_sas.append(run)
-        bf = res.footprint
-        fp.shuffle += bf.shuffle
-        fp.fetch_request += bf.fetch_request
-        fp.fetch_response += bf.fetch_response
-        fp.rounds = max(fp.rounds, bf.rounds)
-        fp.dropped += bf.dropped
-        fp.peak_records = max(fp.peak_records, res.stats["num_suffixes"])
-        block_stats.append(res.stats)
-        if jr is not None and isinstance(run, np.memmap):
-            path, task = scratch.last_spill
-            rec = {
-                "t": "block", "i": i,
-                "run": os.path.basename(path),
-                "run_crc": crc32_array(sa_b),
-                "rows": int(sa_b.size),
-                "stats": res.stats,
-                "fpc": {
-                    "shuffle": int(bf.shuffle),
-                    "fetch_request": int(bf.fetch_request),
-                    "fetch_response": int(bf.fetch_response),
-                    "rounds": int(bf.rounds),
-                    "dropped": int(bf.dropped),
-                },
-            }
-            pending_journal.append((rec, task))
-            _flush_journal()
-        t_build += time.perf_counter() - t0
-    if scratch is not None:
-        scratch.drain_spills()  # spilled runs must be on disk before reads
-    if jr is not None:
-        _flush_journal(force=True)  # every run is durable now
+        with span("superblock.stage", timers, "t_stage_s"):
+            entry = prefetched.pop(i, None)
+            if entry is not None:
+                task, reg = entry
+                pipeline_point("stage:collect")
+                block = task.result()  # staged in the background, not cached
+                store.note_staged(lo, hi, block.nbytes)
+                if reg:
+                    store.add_frontier(-reg)
+                    pf_registered -= reg
+            else:
+                block = store.stage_items(lo, hi)  # transient staging, not cached
+            _submit_stages(i + 1)  # overlap: next blocks stage while this builds
+        with span("superblock.block", timers, "t_build_s"):
+            pipeline_point("build:block")
+            if plan.text_mode:
+                res = build_suffix_array(block, cfg=cfg, mesh=mesh)
+                sa_b = res.suffix_array + lo
+            else:
+                lens_b = None if lengths is None else np.asarray(lengths)[lo:hi]
+                res = build_suffix_array(block, lengths=lens_b, cfg=cfg, mesh=mesh)
+                sa_b = res.suffix_array + (np.int64(lo) << plan.stride_bits)
+            run = keep_run(sa_b)
+            local_sas.append(run)
+            bf = res.footprint
+            fp.shuffle += bf.shuffle
+            fp.fetch_request += bf.fetch_request
+            fp.fetch_response += bf.fetch_response
+            fp.rounds = max(fp.rounds, bf.rounds)
+            fp.dropped += bf.dropped
+            fp.peak_records = max(fp.peak_records, res.stats["num_suffixes"])
+            block_stats.append(res.stats)
+            timers["t_device_s"] += res.stats["t_device_s"]
+            if jr is not None and isinstance(run, np.memmap):
+                path, task = scratch.last_spill
+                rec = {
+                    "t": "block", "i": i,
+                    "run": os.path.basename(path),
+                    "run_crc": crc32_array(sa_b),
+                    "rows": int(sa_b.size),
+                    "stats": res.stats,
+                    "fpc": {
+                        "shuffle": int(bf.shuffle),
+                        "fetch_request": int(bf.fetch_request),
+                        "fetch_response": int(bf.fetch_response),
+                        "rounds": int(bf.rounds),
+                        "dropped": int(bf.dropped),
+                    },
+                }
+                pending_journal.append((rec, task))
+                _flush_journal()
+    with span("superblock.drain"):
+        if scratch is not None:
+            scratch.drain_spills()  # spilled runs must be on disk before reads
+        if jr is not None:
+            _flush_journal(force=True)  # every run is durable now
 
     # ---- phase 3: boundary-exact merge via the store -------------------
-    t_merge0 = time.perf_counter()
+    pre_requests = store.requests
+    pre_fetch_s = store.timers["t_fetch_s"]
+    with span("merge", timers, "t_merge_s"):
+        sa, sink, refiner, cur, peak_candidates = _merge_phase(
+            store, backend, cfg, sb, plan, local_sas, block_stats,
+            original_corpus=original_corpus, lengths=lengths, mesh=mesh,
+            streaming=streaming, keep_run=keep_run, scratch=scratch,
+            frontier=frontier, jr=jr, pipe=pipe, sinks=sinks, timers=timers)
+    timers["t_fetch_s"] = store.timers["t_fetch_s"] - pre_fetch_s
+    if sanitize_enabled(sb):
+        check_footprint(store, backend)
+
+    dev_req = refiner.requests if refiner else 0
+    dev_req_bytes = refiner.request_bytes if refiner else 0
+    dev_resp_bytes = refiner.response_bytes if refiner else 0
+    fp.fetch_request += store.request_bytes + dev_req_bytes
+    fp.fetch_response += store.response_bytes + dev_resp_bytes
+    fp.output = int(sa.shape[0]) * 8
+    fp.peak_records = max(fp.peak_records, store.peak_windows,
+                          refiner.peak_records if refiner else 0,
+                          peak_candidates, sink.max_piece)
+    fp.materialized = fp.peak_records * 16
+    fp.peak_resident_bytes = store.peak_resident_bytes
+
+    stats = {
+        "num_suffixes": int(sa.shape[0]),
+        "emitted": int(sa.shape[0]),
+        "superblocks": plan.num_superblocks,
+        "capacity_records": plan.capacity_records,
+        "peak_records": fp.peak_records,
+        "merge_algorithm": sb.merge_algorithm,
+        "merge_backend": sb.merge_backend,
+        "merge_pieces": sink.pieces,
+        "max_piece": int(sink.max_piece),
+        "merge_fetch_requests": int(store.requests - pre_requests) + dev_req,
+        # store + device-refiner counters are merge-only (neither serves any
+        # phase-2 fetch)
+        "merge_fetch_bytes": int(
+            store.request_bytes + store.response_bytes
+            + dev_req_bytes + dev_resp_bytes
+        ),
+        "merge_fetch_rounds": int(store.rounds)
+        + (refiner.rounds if refiner else 0),
+        "merge_retries": int(store.retries),
+        "merge_cursor_peak_windows": cur.peak_cached_windows,
+        "block_rounds": [s["rounds"] for s in block_stats],
+        "dropped": fp.dropped,
+        "unresolved": sum(s["unresolved"] for s in block_stats),
+        # store-backend residency
+        "store_backend": "chunked" if streaming else "memory",
+        "corpus_bytes": backend.corpus_bytes,
+        "peak_resident_bytes": fp.peak_resident_bytes,
+        "store_cache_hits": backend.cache_hits,
+        "store_cache_misses": backend.cache_misses,
+        "store_cache_hit_rate": backend.hit_rate,
+        "spilled_runs": scratch.spilled_runs if scratch else 0,
+        "spilled_bytes": scratch.spilled_bytes if scratch else 0,
+        "emit_lcp": bool(sb.emit_lcp),
+        "sanitized": sanitize_enabled(sb),
+        # crash-safety layer: journal replay + retrying store
+        "journaled": jr is not None,
+        "journal_hits": int(journal_hits),
+        "store_retry_attempts": int(getattr(backend, "retry_attempts", 0)),
+        "store_retried_calls": int(getattr(backend, "retried_calls", 0)),
+        "pipeline_depth": int(sb.pipeline_depth),
+        # phase seconds: what each overlap in the pipelined schedule can
+        # hide behind (staging behind t_build_s, refill gathers inside
+        # t_merge_s), and the merge's own phases inside t_merge_s
+        **timers,
+    }
+    res = SAResult(suffix_array=sa, footprint=fp, stats=stats, lcp=sink.lcp)
+    if sb.write_manifest:
+        _write_index_manifest(res, backend, cfg, sb, scratch)
+    if jr is not None:
+        # terminal record, then retire the journal: the build is complete
+        # and the index artifacts are published — nothing left to resume.
+        jr.append({"t": "done", "rows": int(sa.shape[0])})
+        jr.finalize()
+    return res
+
+
+def _merge_phase(store, backend, cfg, sb, plan, local_sas, block_stats, *,
+                 original_corpus, lengths, mesh, streaming, keep_run, scratch,
+                 frontier, jr, pipe, sinks, timers):
+    """Phase 3 of :func:`_build_superblock_phases`: merge the blocks' sorted
+    runs into the final order through the store.  Returns ``(sa, sink,
+    refiner, cursor, peak_candidates)``; the merge's phases add their
+    seconds to ``timers``."""
     samples = max(1, min(
         sb.samples_per_block,
         plan.capacity_records // plan.num_superblocks,
     ))
     cap = plan.capacity_records
-    pre_requests = store.requests
     total_suffixes = int(sum(r.size for r in local_sas))
     out_path = (os.path.join(sb.spill_dir, "suffix_array.npy")
                 if sb.spill_dir is not None else None)
@@ -1758,7 +1866,7 @@ def _build_superblock_phases(
             peak_candidates = _merge_path_runs(
                 store, runs + risk_pieces, sink, cap, sb.merge_tile,
                 cfg.use_pallas, refiner=refiner, frontier=frontier,
-                executor=pipe,
+                executor=pipe, timers=timers,
             )
         else:
             # every suffix was at risk: the re-ranked pieces already are
@@ -1782,80 +1890,7 @@ def _build_superblock_phases(
         else:
             for p in risk_pieces:
                 sink.append(p)
-    sa = sink.result()
-    t_merge = time.perf_counter() - t_merge0
-    if sanitize_enabled(sb):
-        check_footprint(store, backend)
-
-    dev_req = refiner.requests if refiner else 0
-    dev_req_bytes = refiner.request_bytes if refiner else 0
-    dev_resp_bytes = refiner.response_bytes if refiner else 0
-    fp.fetch_request += store.request_bytes + dev_req_bytes
-    fp.fetch_response += store.response_bytes + dev_resp_bytes
-    fp.output = int(sa.shape[0]) * 8
-    fp.peak_records = max(fp.peak_records, store.peak_windows,
-                          refiner.peak_records if refiner else 0,
-                          peak_candidates, sink.max_piece)
-    fp.materialized = fp.peak_records * 16
-    fp.peak_resident_bytes = store.peak_resident_bytes
-
-    stats = {
-        "num_suffixes": int(sa.shape[0]),
-        "emitted": int(sa.shape[0]),
-        "superblocks": plan.num_superblocks,
-        "capacity_records": plan.capacity_records,
-        "peak_records": fp.peak_records,
-        "merge_algorithm": sb.merge_algorithm,
-        "merge_backend": sb.merge_backend,
-        "merge_pieces": sink.pieces,
-        "max_piece": int(sink.max_piece),
-        "merge_fetch_requests": int(store.requests - pre_requests) + dev_req,
-        # store + device-refiner counters are merge-only (neither serves any
-        # phase-2 fetch)
-        "merge_fetch_bytes": int(
-            store.request_bytes + store.response_bytes
-            + dev_req_bytes + dev_resp_bytes
-        ),
-        "merge_fetch_rounds": int(store.rounds)
-        + (refiner.rounds if refiner else 0),
-        "merge_retries": int(store.retries),
-        "merge_cursor_peak_windows": cur.peak_cached_windows,
-        "block_rounds": [s["rounds"] for s in block_stats],
-        "dropped": fp.dropped,
-        "unresolved": sum(s["unresolved"] for s in block_stats),
-        # store-backend residency (PR 3)
-        "store_backend": "chunked" if streaming else "memory",
-        "corpus_bytes": backend.corpus_bytes,
-        "peak_resident_bytes": fp.peak_resident_bytes,
-        "store_cache_hits": backend.cache_hits,
-        "store_cache_misses": backend.cache_misses,
-        "store_cache_hit_rate": backend.hit_rate,
-        "spilled_runs": scratch.spilled_runs if scratch else 0,
-        "spilled_bytes": scratch.spilled_bytes if scratch else 0,
-        "emit_lcp": bool(sb.emit_lcp),
-        "sanitized": sanitize_enabled(sb),
-        # crash-safety layer (PR 10): journal replay + retrying store
-        "journaled": jr is not None,
-        "journal_hits": int(journal_hits),
-        "store_retry_attempts": int(getattr(backend, "retry_attempts", 0)),
-        "store_retried_calls": int(getattr(backend, "retried_calls", 0)),
-        "pipeline_depth": int(sb.pipeline_depth),
-        # phase wall-times: what each overlap in the pipelined schedule can
-        # hide behind (staging behind t_build_s, refill gathers inside
-        # t_merge_s) — benchmarks.build calibrates its throttle from these.
-        "t_stage_s": round(t_stage, 6),
-        "t_build_s": round(t_build, 6),
-        "t_merge_s": round(t_merge, 6),
-    }
-    res = SAResult(suffix_array=sa, footprint=fp, stats=stats, lcp=sink.lcp)
-    if sb.write_manifest:
-        _write_index_manifest(res, backend, cfg, sb, scratch)
-    if jr is not None:
-        # terminal record, then retire the journal: the build is complete
-        # and the index artifacts are published — nothing left to resume.
-        jr.append({"t": "done", "rows": int(sa.shape[0])})
-        jr.finalize()
-    return res
+    return sink.result(), sink, refiner, cur, peak_candidates
 
 
 def _write_index_manifest(
@@ -1886,6 +1921,7 @@ def _write_index_manifest(
     index_io.save_index(
         sb.spill_dir, cfg, backend, res.suffix_array, res.lcp, res.stats,
         corpus_ref=corpus_ref, chunk_items=sb.chunk_records,
+        timers=res.stats,
     )
     res.stats["index_dir"] = sb.spill_dir
 

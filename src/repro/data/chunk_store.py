@@ -38,6 +38,7 @@ from typing import List, Optional
 import numpy as np
 
 from repro.core.integrity import CorruptionError, crc32_bytes, publish_file
+from repro.core.trace import span
 
 MAGIC = b"SACHNK01"
 _HEADER = struct.Struct("<8sIIqqq")
@@ -357,13 +358,15 @@ class ChunkedCorpusReader:
         if m.text_mode:
             buf = self._read_tokens(lo, hi + halo)
             if self.verify:
-                self._check_chunk(ci, buf[:hi - lo])  # halo: next chunk's crc
+                with span("store.chunk_verify"):  # the halo is the next
+                    self._check_chunk(ci, buf[:hi - lo])  # chunk's to check
             return buf
         if halo:
             raise ValueError("halo is a text-mode concept (rows are atomic)")
         rows = self.read_items(lo, hi)
         if self.verify:
-            self._check_chunk(ci, rows)
+            with span("store.chunk_verify"):
+                self._check_chunk(ci, rows)
         return rows
 
     def verify_all(self) -> int:

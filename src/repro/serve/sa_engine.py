@@ -43,6 +43,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.config import SAConfig, SuperblockConfig, replace as cfg_replace
+from repro.core.sanitize import unwrap_backend
 from repro.core.search import masked_cmp_np
 from repro.core.store import (
     ChunkedFileBackend,
@@ -50,6 +51,7 @@ from repro.core.store import (
     InMemoryBackend,
     StoreBackend,
 )
+from repro.core.trace import span
 
 __all__ = ["ShardedSAEngine", "SuffixArrayIndex"]
 
@@ -152,8 +154,9 @@ class ShardedSAEngine:
         self.cache = _ResultCache(cache_budget_bytes)
         self._llcp: Optional[np.ndarray] = None
         self._rlcp: Optional[np.ndarray] = None
-        self.stats: Dict[str, int] = {
+        self.stats: Dict[str, Any] = {
             "queries": 0, "search_rounds": 0, "compare_rounds": 0,
+            "compare_rows": 0, "t_compare_s": 0.0,
         }
         if lcp is not None and n:
             self._build_llcp()
@@ -190,18 +193,22 @@ class ShardedSAEngine:
     # -- batched compares ----------------------------------------------------
     def _cmp_rows(self, win: np.ndarray, pw: np.ndarray, start: np.ndarray,
                   stop: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        """One device (or numpy) masked compare over all live rows."""
+        """One device (or numpy) masked compare over all live rows: the
+        host-to-device transfer, the kernel and its result back on the
+        host."""
         self.stats["compare_rounds"] += 1
-        if self.use_pallas:
-            from repro.kernels import ops as kops
+        self.stats["compare_rows"] += int(win.shape[0])
+        with span("serve.compare", self.stats, "t_compare_s"):
+            if self.use_pallas:
+                from repro.kernels import ops as kops
 
-            out = kops.pattern_cmp(
-                win.astype(np.int32), pw.astype(np.int32),
-                start.astype(np.int32), stop.astype(np.int32),
-                block=self.block,
-            )
-            return out[:, 0], out[:, 1].astype(np.int64)
-        return masked_cmp_np(win, pw, start, stop)
+                out = kops.pattern_cmp(
+                    win.astype(np.int32), pw.astype(np.int32),
+                    start.astype(np.int32), stop.astype(np.int32),
+                    block=self.block,
+                )
+                return out[:, 0], out[:, 1].astype(np.int64)
+            return masked_cmp_np(win, pw, start, stop)
 
     def _compare_batch(
         self,
@@ -260,8 +267,9 @@ class ShardedSAEngine:
             return np.zeros(q, np.int64)
         g = np.tile(self.splitters, q)
         pi = np.repeat(np.arange(q), s - 1)
-        c, _ = self._compare_batch(
-            g, pat_rows, pat_len, np.zeros(g.shape[0], np.int64), pi=pi)
+        with span("serve.round"):
+            c, _ = self._compare_batch(
+                g, pat_rows, pat_len, np.zeros(g.shape[0], np.int64), pi=pi)
         c = c.reshape(q, s - 1)
         below = (c <= 0) if upper else (c < 0)  # prefix-match counts as <='
         return below.sum(axis=1).astype(np.int64)
@@ -288,43 +296,44 @@ class ShardedSAEngine:
             act = np.flatnonzero(hi - lo > 1)
             if act.size == 0:
                 return hi
-            self.stats["search_rounds"] += 1
-            mid = (lo[act] + hi[act]) >> 1
-            la, ra = l[act], r[act]
-            right = np.zeros(act.size, bool)
-            newl, newr = la.copy(), ra.copy()
-            if use_lr:
-                ne = la != ra
-                x = np.where(la > ra, self._llcp[mid], self._rlcp[mid])
-                mx = np.maximum(la, ra)
-                gt, ltm = ne & (x > mx), ne & (x < mx)
-                c1, c2 = la > ra, ra > la
-                # x beyond the deeper endpoint's agreement: mid sides with
-                # that endpoint (l/r carry over); x short of it: mid sides
-                # against it and its own lcp is exactly x.
-                right |= c1 & gt
-                newr = np.where(c1 & ltm, x, newr)
-                right |= c2 & ltm
-                newl = np.where(c2 & ltm, x, newl)
-                need = ~(gt | ltm)
-                t0 = np.where(ne, mx, la)  # proven-equal prefix at the mid
-            else:
-                need = np.ones(act.size, bool)
-                t0 = np.minimum(la, ra)
-            ni = np.flatnonzero(need)
-            if ni.size:
-                ai = act[ni]
-                c, t = self._compare_batch(
-                    np.asarray(self.sa[mid[ni]], np.int64),
-                    pat_rows, pat_len, t0[ni], pi=ai)
-                re = (c < 0) | (c == 0) if upper else (c < 0)
-                right[ni] = re
-                newl[ni] = np.where(re, t, newl[ni])
-                newr[ni] = np.where(re, newr[ni], t)
-            lo[act] = np.where(right, mid, lo[act])
-            hi[act] = np.where(right, hi[act], mid)
-            l[act] = np.where(right, newl, l[act])
-            r[act] = np.where(right, r[act], newr)
+            with span("serve.round"):
+                self.stats["search_rounds"] += 1
+                mid = (lo[act] + hi[act]) >> 1
+                la, ra = l[act], r[act]
+                right = np.zeros(act.size, bool)
+                newl, newr = la.copy(), ra.copy()
+                if use_lr:
+                    ne = la != ra
+                    x = np.where(la > ra, self._llcp[mid], self._rlcp[mid])
+                    mx = np.maximum(la, ra)
+                    gt, ltm = ne & (x > mx), ne & (x < mx)
+                    c1, c2 = la > ra, ra > la
+                    # x beyond the deeper endpoint's agreement: mid sides with
+                    # that endpoint (l/r carry over); x short of it: mid sides
+                    # against it and its own lcp is exactly x.
+                    right |= c1 & gt
+                    newr = np.where(c1 & ltm, x, newr)
+                    right |= c2 & ltm
+                    newl = np.where(c2 & ltm, x, newl)
+                    need = ~(gt | ltm)
+                    t0 = np.where(ne, mx, la)  # proven-equal prefix at the mid
+                else:
+                    need = np.ones(act.size, bool)
+                    t0 = np.minimum(la, ra)
+                ni = np.flatnonzero(need)
+                if ni.size:
+                    ai = act[ni]
+                    c, t = self._compare_batch(
+                        np.asarray(self.sa[mid[ni]], np.int64),
+                        pat_rows, pat_len, t0[ni], pi=ai)
+                    re = (c < 0) | (c == 0) if upper else (c < 0)
+                    right[ni] = re
+                    newl[ni] = np.where(re, t, newl[ni])
+                    newr[ni] = np.where(re, newr[ni], t)
+                lo[act] = np.where(right, mid, lo[act])
+                hi[act] = np.where(right, hi[act], mid)
+                l[act] = np.where(right, newl, l[act])
+                r[act] = np.where(right, r[act], newr)
 
     # -- public batched queries ---------------------------------------------
     def ranges(self, patterns: Sequence) -> np.ndarray:
@@ -396,6 +405,20 @@ class ShardedSAEngine:
         ]
 
     def engine_stats(self) -> Dict[str, Any]:
+        """Running totals since the engine was made.
+
+        ``cache_hits`` / ``cache_misses`` / ``cache_resident_bytes`` are the
+        hot-pattern *result* cache's (a query answered without a search).
+        ``chunk_hits`` / ``chunk_misses`` / ``chunk_evictions`` are the
+        corpus *chunk* cache's (``ChunkedFileBackend``'s LRU, one count per
+        chunk a window fetch touches); 0 on a backend without chunks.
+        ``compare_rows`` counts the live rows sent to the compare over all
+        rounds.  Seconds: ``t_compare_s`` in the compare (transfer, kernel,
+        result back), ``t_fetch_s`` in the store's window fetches,
+        ``t_chunk_read_s`` in chunk-cache misses (eviction, read, crc).
+        """
+        backend = unwrap_backend(self.store.backend)
+        chunked = isinstance(backend, ChunkedFileBackend)
         return {
             **self.stats,
             "num_shards": self.num_shards,
@@ -403,8 +426,14 @@ class ShardedSAEngine:
             "cache_hits": self.cache.hits,
             "cache_misses": self.cache.misses,
             "cache_resident_bytes": self.cache.resident_bytes,
+            "chunk_hits": backend.cache_hits if chunked else 0,
+            "chunk_misses": backend.cache_misses if chunked else 0,
+            "chunk_evictions": backend.evictions if chunked else 0,
             "store_requests": self.store.requests,
             "store_response_bytes": self.store.response_bytes,
+            "t_fetch_s": self.store.timers["t_fetch_s"],
+            "t_chunk_read_s": (backend.timers["t_chunk_read_s"] if chunked
+                               else 0.0),
         }
 
 
@@ -495,6 +524,7 @@ class SuffixArrayIndex:
                 cache_budget_bytes=sb.cache_budget_bytes,
                 **engine_kw,
             )
+            res.stats["t_open_s"] = idx.build_stats["t_open_s"]
             idx.build_stats = res.stats
             return idx
         backend = _serving_backend(corpus, cfg, sb)
@@ -520,19 +550,23 @@ class SuffixArrayIndex:
         ``verify`` sets the integrity posture (``"eager"`` / ``"lazy"`` /
         ``"off"`` — see :func:`repro.core.index_io.open_index`); failures
         raise :class:`repro.core.integrity.CorruptionError` naming the
-        artifact.
+        artifact.  ``build_stats`` are the manifest's, with ``t_open_s``:
+        the seconds this open took.
         """
         from repro.core import index_io
 
-        backend, sa, lcp, manifest = index_io.open_index(
-            index_dir, store_backend=store_backend,
-            cache_budget_bytes=cache_budget_bytes, verify=verify,
-        )
-        store = CorpusStore(None, SAConfig(**manifest["sa_config"]),
-                            backend=backend,
-                            request_capacity=request_capacity)
+        opened: Dict[str, float] = {}
+        with span("index.open", opened, "t_open_s"):
+            backend, sa, lcp, manifest = index_io.open_index(
+                index_dir, store_backend=store_backend,
+                cache_budget_bytes=cache_budget_bytes, verify=verify,
+            )
+            store = CorpusStore(None, SAConfig(**manifest["sa_config"]),
+                                backend=backend,
+                                request_capacity=request_capacity)
         return cls(store, sa, lcp=lcp, index_dir=index_dir,
-                   stats=manifest.get("stats"), **engine_kw)
+                   stats={**(manifest.get("stats") or {}), **opened},
+                   **engine_kw)
 
     def save(self, index_dir: str) -> str:
         """Write the persistent layout; returns the manifest path.  The
