@@ -428,6 +428,16 @@ class ChunkedFileBackend(StoreBackend):
     within a chunk by construction.  ``read_items`` streams straight from
     the file (pread) without touching the cache — per-superblock staging is
     transient and must not evict the merge's working set.
+
+    One ``gather`` serves the chunks resident at its start first, then loads
+    the missing ones, each in ascending id (so preads stay in file order).
+    Each missing chunk's rows are copied out before the next one loads, so
+    eviction only ever takes chunks the call does not need or has already
+    served: a call misses exactly the chunks it needs that were not resident
+    when it began.  (An ascending sweep over more chunks than the cache
+    holds would evict, as least recent, the resident chunks it reaches
+    later.)  ``reordered_gathers`` counts the calls whose order differed
+    from ascending.
     """
 
     def __init__(self, path: str, cfg: SAConfig, cache_budget_bytes: int = 0,
@@ -461,6 +471,7 @@ class ChunkedFileBackend(StoreBackend):
         self._cache: "OrderedDict[int, np.ndarray]" = OrderedDict()
         self._resident = 0
         self.evictions = 0
+        self.reordered_gathers = 0
         # seconds in the miss path (eviction + read + crc check)
         self.timers = {"t_chunk_read_s": 0.0}
 
@@ -505,10 +516,15 @@ class ChunkedFileBackend(StoreBackend):
             off = (gidx & ((1 << self.stride_bits) - 1)).astype(np.int64)
             off = np.minimum(off + depth * self.k, self.max_len - 1)
             ci = row // self.chunk_items
-        for c in np.unique(ci):
+        chunks = np.unique(ci).tolist()
+        order = ([c for c in chunks if c in self._cache]
+                 + [c for c in chunks if c not in self._cache])
+        if order != chunks:
+            self.reordered_gathers += 1
+        for c in order:
             sel = np.flatnonzero(ci == c)
-            chunk = self._chunk(int(c))
-            base = int(c) * self.chunk_items
+            chunk = self._chunk(c)
+            base = c * self.chunk_items
             if self.text_mode:
                 local = pos[sel] - base  # halo covers the straddle/tail
                 cols = local[:, None] + np.arange(self.k)[None, :]

@@ -411,7 +411,9 @@ class ShardedSAEngine:
         hot-pattern *result* cache's (a query answered without a search).
         ``chunk_hits`` / ``chunk_misses`` / ``chunk_evictions`` are the
         corpus *chunk* cache's (``ChunkedFileBackend``'s LRU, one count per
-        chunk a window fetch touches); 0 on a backend without chunks.
+        chunk a window fetch touches), ``chunk_reordered_gathers`` the
+        gathers that served resident chunks ahead of a missing one with a
+        lower id; 0 on a backend without chunks.
         ``compare_rows`` counts the live rows sent to the compare over all
         rounds.  Seconds: ``t_compare_s`` in the compare (transfer, kernel,
         result back), ``t_fetch_s`` in the store's window fetches,
@@ -429,6 +431,8 @@ class ShardedSAEngine:
             "chunk_hits": backend.cache_hits if chunked else 0,
             "chunk_misses": backend.cache_misses if chunked else 0,
             "chunk_evictions": backend.evictions if chunked else 0,
+            "chunk_reordered_gathers": (backend.reordered_gathers if chunked
+                                        else 0),
             "store_requests": self.store.requests,
             "store_response_bytes": self.store.response_bytes,
             "t_fetch_s": self.store.timers["t_fetch_s"],

@@ -151,6 +151,33 @@ def test_corrupt_chunk_is_never_retried(tmp_path):
     backend.close()
 
 
+def test_corrupt_chunk_after_resident_hits_is_not_cached(tmp_path):
+    """A gather serves its resident chunks before it loads a missing one:
+    a corrupt chunk loaded after those hits still raises, and is not
+    cached."""
+    from repro.data.chunk_store import HEADER_BYTES
+
+    corpus = _corpus()  # 24 rows of 8: 4 chunks of 6 rows
+    path = str(tmp_path / "c.sachunk")
+    write_chunked_corpus(corpus, path, chunk_items=6)
+    _flip_byte(path, HEADER_BYTES + 13 * 8 * 4)  # inside chunk 2
+    ch = ChunkedFileBackend(path, CFG, cache_budget_bytes=4 * 6 * 8 * 4)
+
+    def first_windows(*rows):
+        gidx = np.array(rows, np.int64) << ch.stride_bits
+        return ch.gather(gidx, 0)  # salint: disable=SAL002
+
+    first_windows(18)  # chunk 3 resident
+    resident, hits = ch.resident_bytes, ch.cache_hits
+    for _ in range(2):  # nothing cached for it: the retry reads it again
+        with pytest.raises(CorruptionError, match="chunk 2"):
+            first_windows(12, 18)
+        assert ch.resident_bytes == resident
+    assert ch.cache_hits == hits + 2  # chunk 3 was served first each time
+    assert ch.reordered_gathers == 2
+    ch.close()
+
+
 # ---------------------------------------------------------------------------
 # build journal: crc'd records, torn-tail tolerance
 # ---------------------------------------------------------------------------

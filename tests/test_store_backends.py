@@ -213,6 +213,98 @@ def test_lru_eviction_order_is_least_recent(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# gather visit order: resident chunks first, then the missing ones
+# ---------------------------------------------------------------------------
+
+
+def _chunked(tmp_path, corpus, chunk_items, budget_chunks):
+    """A chunked backend over ``corpus`` whose budget holds exactly
+    ``budget_chunks`` whole chunks (halo included in text mode)."""
+    p = str(tmp_path / "c.sachunk")
+    meta = write_chunked_corpus(corpus, p, chunk_items=chunk_items)
+    full = meta.chunk_bytes + (CFG.prefix_len * 4 if meta.text_mode else 0)
+    return ChunkedFileBackend(p, CFG, cache_budget_bytes=budget_chunks * full)
+
+
+@pytest.mark.parametrize("text_mode", [False, True], ids=["reads", "text"])
+@pytest.mark.parametrize("budget_chunks", [3, 12], ids=["evicting", "roomy"])
+def test_gather_order_windows_exact(tmp_path, text_mode, budget_chunks):
+    """Windows are bit-identical to the whole-corpus reference under any
+    visit order, and the budget holds after every call."""
+    rng = np.random.default_rng(7)
+    if text_mode:  # 10 chunks of 16 tokens, a 4-token tail
+        corpus = rng.integers(1, 5, size=(164,)).astype(np.int32)
+        ch = _chunked(tmp_path, corpus, 16, budget_chunks)
+    else:  # 10 chunks of 4 rows, a 2-row tail
+        corpus = rng.integers(1, 5, size=(42, 9)).astype(np.int32)
+        ch = _chunked(tmp_path, corpus, 4, budget_chunks)
+    mem = InMemoryBackend(corpus, CFG)
+    assert ch.num_chunks == 11
+    for _ in range(12):
+        m = int(rng.integers(1, 12))
+        if text_mode:
+            gidx = rng.integers(0, mem.n, size=(m,)).astype(np.int64)
+            # windows straddling a chunk edge, served from the halo
+            edges = np.arange(13, mem.n, 16, dtype=np.int64)
+            k = min(m, edges.size)
+            gidx[:k] = rng.choice(edges, size=k, replace=False)
+        else:
+            row = rng.integers(0, mem.n, size=(m,)).astype(np.int64)
+            off = rng.integers(0, mem.row_len + 1, size=(m,)).astype(np.int64)
+            gidx = (row << mem.stride_bits) | off
+        depth = rng.integers(0, mem.max_len // mem.k + 2,
+                             size=(m,)).astype(np.int64)
+        np.testing.assert_array_equal(ch.gather(gidx, depth),
+                                      mem.gather(gidx, depth))
+        assert ch.resident_bytes <= ch.cache_budget_bytes
+    assert ch.reordered_gathers > 0
+    assert (ch.evictions > 0) == (budget_chunks < ch.num_chunks)
+    ch.close()
+
+
+@pytest.mark.parametrize("budget_chunks", [1, 3, 5, 7])
+def test_repeated_sweep_misses_only_what_was_not_resident(tmp_path,
+                                                          budget_chunks):
+    """A sweep over C chunks with room for B < C: every sweep after the
+    first misses exactly C - B (an ascending order would miss all C)."""
+    reads = np.random.default_rng(5).integers(
+        1, 5, size=(32, 6)).astype(np.int32)
+    ch = _chunked(tmp_path, reads, 4, budget_chunks)  # C = 8
+    c = ch.num_chunks
+    first = np.arange(reads.shape[0], dtype=np.int64) << ch.stride_bits
+    ch.gather(first, 0)
+    assert (ch.cache_misses, ch.cache_hits) == (c, 0)
+    for sweep in range(1, 4):
+        misses, hits, ev = ch.cache_misses, ch.cache_hits, ch.evictions
+        ch.gather(first, 0)
+        assert ch.cache_misses - misses == c - budget_chunks
+        assert ch.cache_hits - hits == budget_chunks
+        assert ch.evictions - ev == c - budget_chunks
+        assert ch.resident_bytes <= ch.cache_budget_bytes
+    assert ch.reordered_gathers == 3
+    ch.close()
+
+
+def test_reordered_gathers_counts_exactly(tmp_path):
+    reads = np.arange(1, 97, dtype=np.int32).reshape(16, 6) % 4 + 1
+    ch = _chunked(tmp_path, reads, 4, 2)  # 4 chunks, room for 2
+
+    def touch(*chunks):
+        rows = np.array([4 * c for c in chunks], np.int64)
+        ch.gather(rows << ch.stride_bits, 0)
+        return ch.reordered_gathers, ch.cache_misses, ch.cache_hits
+
+    assert touch(0) == (0, 1, 0)
+    assert touch(1) == (0, 2, 0)  # cache: 0, 1
+    assert touch(0, 1, 2) == (0, 3, 2)  # already ascending; evicts 0
+    assert touch(0, 2) == (1, 4, 3)  # 2 served before 0 loads; evicts 1
+    assert touch(0, 3) == (1, 5, 4)  # ascending; evicts 2
+    assert touch(1, 3) == (2, 6, 5)  # 3 served before 1 loads; evicts 0
+    assert touch(3, 1) == (2, 6, 7)  # both resident: ascending
+    ch.close()
+
+
+# ---------------------------------------------------------------------------
 # WindowCursor eviction paths + store frontier accounting
 # ---------------------------------------------------------------------------
 
