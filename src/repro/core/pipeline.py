@@ -13,7 +13,10 @@ Dataflow per device (inside ``shard_map`` over a flat 1-D mesh):
 
 Static-shape discipline (TPU): the shuffle capacity is sized *exactly* by a
 cheap histogram pre-pass (``cfg.adaptive``, two-phase planning — the TPU
-analogue of the paper's up-front sampling); store fetches that overflow their
+analogue of the paper's up-front sampling).  Without it the capacity is the
+static ``shuffle_slack`` share, one compiled program per job shape; a job
+whose shuffle overflows it is rebuilt once at the exact capacity, so no
+suffix is ever left out of the result.  Store fetches that overflow their
 capacity are retried with **group-synchronous advancement**: a tie group only
 consumes its next K-token window when every active member's request was
 served, so partial service can never produce an inconsistent comparison.
@@ -517,21 +520,42 @@ def build_suffix_array(
         data = jax.device_put(data, sharding)
         lens = jax.device_put(lens, sharding)
         halo = jax.device_put(halo, sharding)
-    # the device's share: histogram pre-pass, pipeline, outputs on the host
-    with span("build.device", timers, "t_device_s"):
-        shuffle_cap = None
-        if cfg.adaptive:
-            shuffle_cap = _exact_shuffle_cap(
-                np.shape(corpus), cfg, mesh, data, lens, halo
-            )
+    def run(shuffle_cap):
         jitted, info = make_pipeline(
             np.shape(corpus), cfg, mesh, lengths, shuffle_cap=shuffle_cap
         )
         ih, il, statmat = jitted(data, lens, halo)
-        ih, il, statmat = np.asarray(ih), np.asarray(il), np.asarray(statmat)
+        return (np.asarray(ih), np.asarray(il), np.asarray(statmat),
+                d * d * info["shuffle_cap"])
+
+    def exact_cap():
+        return _exact_shuffle_cap(np.shape(corpus), cfg, mesh, data, lens,
+                                  halo)
+
+    # the device's share: histogram pre-pass, pipeline, outputs on the host
+    with span("build.device", timers, "t_device_s"):
+        ih, il, statmat, slots = run(exact_cap() if cfg.adaptive else None)
+        dropped = int(statmat[:, 6].sum())
+        if dropped:
+            # the static capacity overflowed: a short suffix array is never
+            # returned.  The exact capacity drops nothing by construction.
+            with span("build.rebuild"):
+                ih, il, statmat, more = run(exact_cap())
+            slots += more
+            if int(statmat[:, 6].sum()):
+                raise RuntimeError(
+                    "shuffle dropped records at the exact capacity")
     with span("build.finalize"):
         res = _finalize(ih, il, statmat, corpus, cfg)
     res.stats.update(timers)
+    # the all_to_all's record slots over every attempt, and the valid
+    # records the last one delivered (== num_suffixes)
+    res.stats.update(
+        shuffle_slots=slots,
+        shuffle_records=int(statmat[:, 0].sum()),
+        shuffle_dropped=dropped,
+        shuffle_rebuilds=int(dropped > 0),
+    )
     return res
 
 

@@ -133,3 +133,95 @@ def test_store_primitives_8dev(run_multidev):
         """
     )
     assert "OK" in out
+
+
+STATIC_SLACKS = (2.0, 0.5)
+
+
+@pytest.fixture(scope="module")
+def static_builds(run_multidev, tmp_path_factory):
+    """One 4-device subprocess: ``SuffixArrayIndex.build`` under the static
+    shuffle capacity (``adaptive=False``) at each of ``STATIC_SLACKS``,
+    published to an index directory and reopened; per slack, what the
+    index holds against the plain reference and its shuffle counters."""
+    import json
+
+    root = tmp_path_factory.mktemp("static")
+    out = run_multidev(
+        f"""
+        import json
+        import numpy as np, jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.config import SAConfig
+        from repro.core import pipeline
+        from repro.core.oracle import lcp_kasai, naive_sa_reads
+        from repro.data.corpus import synth_dna_reads
+        from repro.serve.sa_engine import SuffixArrayIndex
+
+        reads = synth_dna_reads(64, 50, seed=7)  # 16x coverage
+        r, l = reads.shape
+        stride = int(np.ceil(np.log2(l + 1)))
+        sa_ref = naive_sa_reads(reads, stride_bits=stride)
+        # the read set as one text: read i ends in a separator below every
+        # token and below read j's for i < j, so ties order by read as the
+        # global index does, and no separator matches another
+        text = np.concatenate(
+            [reads, (np.arange(r) - r)[:, None]], axis=1).ravel()
+        pos = (sa_ref >> stride) * (l + 1) + (sa_ref & ((1 << stride) - 1))
+        lcp_ref = lcp_kasai(text, pos)
+        mesh = Mesh(np.array(jax.devices()), ("sa",))
+        out = {{}}
+        for slack in {STATIC_SLACKS!r}:
+            cfg = SAConfig(vocab_size=4, adaptive=False, shuffle_slack=slack)
+            d = f"{root}/slack{{slack}}"
+            SuffixArrayIndex.build(reads, cfg=cfg, index_dir=d,
+                                   mesh=mesh).close()
+            with SuffixArrayIndex.open(d) as idx:
+                info = pipeline.plan(reads.shape, cfg, 4)
+                sh = NamedSharding(mesh, P("sa"))
+                data, lens, halo = (jax.device_put(a, sh) for a in
+                                    pipeline._shard_inputs(reads, None, cfg,
+                                                           4, info))
+                out[str(slack)] = dict(
+                    sa_exact=bool(np.array_equal(idx.sa, sa_ref)),
+                    lcp_exact=bool(np.array_equal(idx.lcp, lcp_ref)),
+                    sa_len=int(idx.sa.shape[0]),
+                    static_cap=info["shuffle_cap"],
+                    exact_cap=pipeline._exact_shuffle_cap(
+                        reads.shape, cfg, mesh, data, lens, halo),
+                    **{{k: idx.build_stats[k] for k in (
+                        "num_suffixes", "dropped", "unresolved",
+                        "shuffle_slots", "shuffle_records",
+                        "shuffle_dropped", "shuffle_rebuilds")}})
+        print("RESULT " + json.dumps(out))
+        """,
+        devices=4, timeout=300)
+    line = next(s for s in out.splitlines() if s.startswith("RESULT "))
+    return json.loads(line[len("RESULT "):])
+
+
+@pytest.mark.parametrize("slack", STATIC_SLACKS)
+def test_static_capacity_publishes_exact_index(static_builds, slack):
+    """The static posture publishes the reference's SA and LCP whether its
+    shuffle fits (slack 2.0) or overflows and is rebuilt (slack 0.5)."""
+    got = static_builds[str(slack)]
+    assert got["sa_exact"] and got["lcp_exact"], got
+    assert got["sa_len"] == got["num_suffixes"] == 64 * 51
+    assert got["dropped"] == 0 and got["unresolved"] == 0
+    if slack >= 1.0:
+        assert got["shuffle_dropped"] == 0 and got["shuffle_rebuilds"] == 0
+    else:
+        assert got["shuffle_dropped"] > 0 and got["shuffle_rebuilds"] == 1
+
+
+def test_shuffle_counters_reach_build_stats(static_builds):
+    """``shuffle_slots`` counts d*d*cap a pipeline run (both runs where the
+    static capacity overflowed), ``shuffle_records`` every suffix once;
+    both read back from the reopened index's manifest."""
+    fits, rebuilt = static_builds["2.0"], static_builds["0.5"]
+    assert fits["static_cap"] == 64 // 4 * 51 * 2 // 4
+    assert fits["shuffle_slots"] == 4 * 4 * fits["static_cap"]
+    assert rebuilt["shuffle_slots"] == 4 * 4 * (rebuilt["static_cap"]
+                                                + rebuilt["exact_cap"])
+    for got in (fits, rebuilt):
+        assert got["shuffle_records"] == got["num_suffixes"]
