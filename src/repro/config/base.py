@@ -382,8 +382,10 @@ class SuperblockConfig:
     ``emit_lcp``: also produce the adjacent-pair LCP array (the query
       engine's O(m + log n) companion artifact, ``repro.core.lcp``).  The
       out-of-core merge computes it as pieces stream out (emit order is
-      final order, so each pair costs one adjacent compare); single-pass
-      builds recompute it post-hoc.  Streamed to ``{spill_dir}/lcp.npy``
+      final order, so each pair costs one adjacent compare); a single-pass
+      build names it on the device, in the refinement round that splits
+      each pair, and fetches on the host only the pairs the device cannot
+      name (``repro.core.lcp.finish_lcp``).  Streamed to ``{spill_dir}/lcp.npy``
       when spilling, host array otherwise.  Returned as ``SAResult.lcp``.
     ``write_manifest``: finalize ``spill_dir`` as a reopenable index
       directory (``repro.core.index_io``): ``manifest.json`` + the SA (+

@@ -7,25 +7,32 @@ first-class artifact of index construction).  The serving engine
 so a batched binary search compares only tokens the pattern has not already
 matched.
 
-Two producers, one definition:
+Three producers, one definition:
 
+* the single-pass device build names each pair's LCP in the refinement
+  round that splits it (``build_suffix_array(lcp=True)``), and
+  :func:`finish_lcp` fetches only the pairs it could not name: each
+  device's first pair, ties left at the round cap, identical suffixes of
+  variable-length reads;
 * during the out-of-core merge, emit order **is** final order, so the merge
   sink computes each adjacent pair's LCP as pieces stream out
   (``SuperblockConfig.emit_lcp``; see ``core/superblock._OutputSink``);
 * :func:`lcp_from_sa` recomputes the whole array post-hoc from any built SA
-  (the single-pass build's path, and the facade's fallback).
+  (the reference the tests hold the others to).
 
-Both reduce to :func:`pairwise_lcp`: progressive K-token window fetches from
-the :class:`~repro.core.store.CorpusStore`, stopping at the first token
-mismatch **or** the first position where both windows carry the padding ``0``
-(both suffixes ended — contents equal up to their common length).  Real
-tokens are >= 1, so this is exact under the store's zero-padding convention.
+The host's share reduces to :func:`pairwise_lcp`: progressive K-token
+window fetches from the :class:`~repro.core.store.CorpusStore`, stopping at
+the first token mismatch **or** the first position where both windows carry
+the padding ``0`` (both suffixes ended — contents equal up to their common
+length).  Real tokens are >= 1, so this is exact under the store's
+zero-padding convention.
 """
 from __future__ import annotations
 
 import numpy as np
 
 from repro.core.store import CorpusStore
+from repro.core.types import LCP_UNNAMED
 
 
 def pairwise_lcp(store: CorpusStore, a: np.ndarray, b: np.ndarray) -> np.ndarray:
@@ -76,3 +83,16 @@ def lcp_from_sa(store: CorpusStore, sa: np.ndarray,
             np.asarray(sa[lo:hi], np.int64),
         )
     return out
+
+
+def finish_lcp(store: CorpusStore, sa: np.ndarray, lcp: np.ndarray,
+               batch: int = 1 << 16) -> int:
+    """Fill the ``LCP_UNNAMED`` entries of ``lcp`` (the SA's LCP array as
+    the device build named it) in place, by :func:`pairwise_lcp` on those
+    pairs only; returns how many it filled."""
+    at = np.flatnonzero(lcp[1:] == LCP_UNNAMED) + 1
+    for lo in range(0, at.size, batch):
+        i = at[lo : lo + batch]
+        lcp[i] = pairwise_lcp(store, np.asarray(sa[i - 1], np.int64),
+                              np.asarray(sa[i], np.int64))
+    return int(at.size)

@@ -52,6 +52,7 @@ from repro.core.store import StoreSpec, mget_window, token_bytes
 from repro.core.trace import span
 from repro.core.types import (
     KEY_SENTINEL,
+    LCP_UNNAMED,
     WORD_BITS,
     WORD_MOD,
     Footprint,
@@ -76,22 +77,52 @@ def _tied(g: jnp.ndarray) -> jnp.ndarray:
     return (g == prev) | (g == nxt)
 
 
+def _remaining(ih, il, *, text_mode, text_len, uniform_len, stride_bits):
+    """Tokens left in each suffix, where that is locally computable (text
+    mode / uniform-length reads)."""
+    if text_mode:
+        return text_len - il
+    _, off = unpack_index(ih, il, stride_bits)
+    return uniform_len - off
+
+
 def _suffix_exhausted(ih, il, depth, *, text_mode, text_len, uniform_len,
                       stride_bits, k):
     """Analytic exhaustion: the first ``depth * k`` tokens already covered the
     whole suffix (locally computable in text mode / uniform-length reads)."""
-    if text_mode:
-        rem = text_len - il
-    else:
-        _, off = unpack_index(ih, il, stride_bits)
-        rem = uniform_len - off
+    rem = _remaining(ih, il, text_mode=text_mode, text_len=text_len,
+                     uniform_len=uniform_len, stride_bits=stride_bits)
     return rem <= depth * k
+
+
+def _shared_tokens(hi, lo, cfg):
+    """Per position p >= 1: the leading tokens the packed K-token keys at
+    p - 1 and p have in common — elementwise compares of the key words'
+    prefixes, no gather.  Where the two keys differ this is the count the
+    two suffixes share: a key's tokens after its suffix's end are all
+    ``0``, so the keys differ before any end they both carry."""
+    cpw = cfg.resolved_chars_per_word()
+    bits = max(1, cfg.vocab_size.bit_length())
+
+    def common(a, b):  # the longest common prefix of two words, in tokens
+        n = jnp.zeros(a.shape, jnp.int32)
+        for j in range(1, cpw + 1):  # prefixes as encoding.pack_columns packs
+            if cfg.packing == "base":
+                q = (cfg.vocab_size + 1) ** (cpw - j)
+                n = n + (a // q == b // q)
+            else:
+                s = 31 - bits * j
+                n = n + ((a >> s) == (b >> s))
+        return n
+
+    n = common(hi[:-1], hi[1:])
+    return n + jnp.where(n == cpw, common(lo[:-1], lo[1:]), 0)
 
 
 @jax.named_scope("refine")
 def _refine_tie_groups(g, ih, il, exhausted, *, store_local, spec, cfg,
                        analytic, text_mode, text_len, uniform_len,
-                       stride_bits, hard_cap):
+                       stride_bits, hard_cap, lcp=None):
     """Group-synchronous window-refinement loop (the reduce-phase core).
 
     Shared by the full pipeline (:func:`_device_fn`) and the out-of-core
@@ -100,6 +131,14 @@ def _refine_tie_groups(g, ih, il, exhausted, *, store_local, spec, cfg,
     (``mget_window``) and re-sort within the group, a group only consuming a
     window when every active member was served.  Runs under ``shard_map``;
     returns the final ``(g, ih, il, exhausted, depth, stats)`` carry.
+
+    Given ``lcp`` (``(n,)`` int32 by sorted position, the pairs named so
+    far), the loop also carries it and returns it last.  A group's start
+    never moves once it exists, and two neighbouring groups share exactly
+    their common windows, so the LCP across a boundary is final in the
+    round the boundary first appears: the group's depth before that round
+    times K plus the leading tokens the two new windows share.  Without it
+    the traced program is the loop alone.
     """
     axis = spec.axis
     n = ih.shape[0]
@@ -117,13 +156,13 @@ def _refine_tie_groups(g, ih, il, exhausted, *, store_local, spec, cfg,
     )
 
     def cond(carry):
-        g, ih, il, exhausted, depth, stats = carry
+        g, ih, il, exhausted, depth, stats, *_ = carry
         active = _tied(g) & ~exhausted & (ih != KEY_SENTINEL)
         total = lax.psum(jnp.sum(active), axis)
         return (total > 0) & (stats["iters"] < hard_cap)
 
     def body(carry):
-        g, ih, il, exhausted, depth, stats = carry
+        g, ih, il, exhausted, depth, stats, *named = carry
         validr = ih != KEY_SENTINEL
         if analytic:
             exhausted = _suffix_exhausted(
@@ -165,6 +204,14 @@ def _refine_tie_groups(g, ih, il, exhausted, *, store_local, spec, cfg,
             ]
         )
         eq = eq & validr
+        if named:
+            # a boundary appears at p: p - 1 and p were one group until now.
+            # p's window sorts above p - 1's, so p advanced this round and
+            # its depth is the group's plus one.
+            split = (g[1:] == g[:-1]) & ~eq[1:]
+            at = (depth[1:] - 1) * k + _shared_tokens(nk_hi, nk_lo, cfg)
+            named = [jnp.concatenate(
+                [named[0][:1], jnp.where(split, at, named[0][1:])])]
         g = run_starts(eq)
         stats = dict(
             iters=stats["iters"] + 1,
@@ -174,9 +221,11 @@ def _refine_tie_groups(g, ih, il, exhausted, *, store_local, spec, cfg,
             retries=stats["retries"] + fs.dropped,
             max_depth=jnp.maximum(stats["max_depth"], jnp.max(depth)),
         )
-        return (g, ih, il, exhausted, depth, stats)
+        return (g, ih, il, exhausted, depth, stats, *named)
 
-    return lax.while_loop(cond, body, (g, ih, il, exhausted, depth0, stats0))
+    named = () if lcp is None else (lcp,)
+    return lax.while_loop(cond, body,
+                          (g, ih, il, exhausted, depth0, stats0, *named))
 
 
 def _map_phase(reads_l, lengths_l, halo_l, *, cfg, rows_per_shard, stride_bits,
@@ -260,8 +309,18 @@ def _device_fn(
     uniform_len: Optional[int],
     text_mode: bool,
     text_len: int,
+    lcp: bool = False,
 ):
-    """Per-device SA pipeline body (runs under shard_map)."""
+    """Per-device SA pipeline body (runs under shard_map).
+
+    With ``lcp`` it also returns each sorted position's LCP with the one
+    before it: named by the first sort's keys and by the refinement round
+    that splits the pair, and for identical suffixes, analytically, by
+    their length.  Pairs it cannot name hold ``LCP_UNNAMED``: every
+    device's first position (its neighbour is on another device), pairs
+    still tied at ``hard_cap``, and identical suffixes of variable-length
+    reads.
+    """
     d = num_shards
     k = cfg.prefix_len
 
@@ -291,6 +350,13 @@ def _device_fn(
     )
     eq = eq & validr
     g = run_starts(eq)
+    lcp0 = None
+    if lcp:
+        lcp0 = jnp.concatenate([
+            jnp.full((1,), LCP_UNNAMED, jnp.int32),
+            jnp.where(eq[1:] | ~validr[1:], LCP_UNNAMED,
+                      _shared_tokens(kh, kl, cfg)),
+        ])
 
     # exhausted = the first depth*K tokens already covered the whole suffix.
     # Analytic when remaining length is locally computable (text mode /
@@ -322,11 +388,11 @@ def _device_fn(
     else:
         store_local = reads_l
 
-    g, ih, il, exhausted, depth, stats = _refine_tie_groups(
+    g, ih, il, exhausted, depth, stats, *named = _refine_tie_groups(
         g, ih, il, exhausted, store_local=store_local, spec=spec, cfg=cfg,
         analytic=analytic, text_mode=text_mode, text_len=text_len,
         uniform_len=uniform_len, stride_bits=stride_bits,
-        hard_cap=2 * max_rounds + 8,
+        hard_cap=2 * max_rounds + 8, lcp=lcp0,
     )
 
     # unresolved = groups still tied and not exhausted when hard_cap hit
@@ -348,7 +414,20 @@ def _device_fn(
             stats["max_depth"],
         ]
     )
-    return ih, il, statvec[None, :]
+    if not lcp:
+        return ih, il, statvec[None, :]
+    (lcp_named,) = named
+    if analytic:
+        # still tied and ended: identical suffixes, sharing their length
+        kw = dict(text_mode=text_mode, text_len=text_len,
+                  uniform_len=uniform_len, stride_bits=stride_bits)
+        ended = _suffix_exhausted(ih, il, depth, k=k, **kw)
+        same = (g[1:] == g[:-1]) & ended[1:] & ended[:-1]
+        lcp_named = jnp.concatenate([
+            lcp_named[:1],
+            jnp.where(same, _remaining(ih, il, **kw)[1:], lcp_named[1:]),
+        ])
+    return ih, il, statvec[None, :], lcp_named
 
 
 def plan(corpus_shape, cfg: SAConfig, num_shards: int, lengths=None):
@@ -421,22 +500,23 @@ def _shard_inputs(corpus, lengths, cfg: SAConfig, d: int, info):
 
 
 def make_pipeline(corpus_shape, cfg: SAConfig, mesh: Mesh, lengths=None,
-                  shuffle_cap: Optional[int] = None):
+                  shuffle_cap: Optional[int] = None, lcp: bool = False):
     """Build the jitted shard_map'd pipeline for given static shapes.
 
     Returns (jitted_fn, info).  Usable both for execution and for
     ``.lower()`` in the multi-pod dry-run.  The jitted program is cached
     per static configuration, so equal-shaped builds (the superblocks of
-    one out-of-core build) trace and compile once.
+    one out-of-core build) trace and compile once.  With ``lcp`` the
+    program also returns the LCP its refinement names (:func:`_device_fn`).
     """
     fn, info = _pipeline_program(tuple(corpus_shape), cfg, mesh,
-                                 lengths is None, shuffle_cap)
+                                 lengths is None, shuffle_cap, lcp)
     return fn, dict(info)
 
 
 @lru_cache(maxsize=16)
 def _pipeline_program(corpus_shape, cfg: SAConfig, mesh: Mesh,
-                      uniform: bool, shuffle_cap: Optional[int]):
+                      uniform: bool, shuffle_cap: Optional[int], lcp: bool):
     d = mesh.devices.size
     info = plan(corpus_shape, cfg, d)
     if not uniform:
@@ -461,10 +541,11 @@ def _pipeline_program(corpus_shape, cfg: SAConfig, mesh: Mesh,
         uniform_len=info["uniform_len"],
         text_mode=info["text_mode"],
         text_len=info["text_len"],
+        lcp=lcp,
     )
     smapped = jax.shard_map(
         fn, mesh=mesh, in_specs=(P(AXIS), P(AXIS), P(AXIS)),
-        out_specs=(P(AXIS), P(AXIS), P(AXIS)),
+        out_specs=(P(AXIS),) * (4 if lcp else 3),
         # interpret-mode Pallas mixes varying/unvarying internals; relax the
         # vma checker when kernels are routed through pallas_call.
         check_vma=not cfg.use_pallas,
@@ -505,10 +586,15 @@ def build_suffix_array(
     lengths=None,
     cfg: SAConfig = SAConfig(),
     mesh: Optional[Mesh] = None,
+    lcp: bool = False,
 ) -> SAResult:
     """Build the suffix array of ``corpus`` with the paper's scheme.
 
     corpus: (R, L) int32 reads (tokens 1..V, 0 padding) or (n,) int32 text.
+    ``lcp``: the caller publishes an LCP array, so the device names it as
+    it sorts; ``SAResult.lcp`` then holds ``LCP_UNNAMED`` at the pairs it
+    could not name (stats ``lcp_device_pairs`` counts the others), for
+    :func:`repro.core.lcp.finish_lcp` to fill.
     """
     mesh = _flat_mesh(mesh)
     d = mesh.devices.size
@@ -522,11 +608,12 @@ def build_suffix_array(
         halo = jax.device_put(halo, sharding)
     def run(shuffle_cap):
         jitted, info = make_pipeline(
-            np.shape(corpus), cfg, mesh, lengths, shuffle_cap=shuffle_cap
+            np.shape(corpus), cfg, mesh, lengths, shuffle_cap=shuffle_cap,
+            lcp=lcp,
         )
-        ih, il, statmat = jitted(data, lens, halo)
+        ih, il, statmat, *named = jitted(data, lens, halo)
         return (np.asarray(ih), np.asarray(il), np.asarray(statmat),
-                d * d * info["shuffle_cap"])
+                [np.asarray(x) for x in named], d * d * info["shuffle_cap"])
 
     def exact_cap():
         return _exact_shuffle_cap(np.shape(corpus), cfg, mesh, data, lens,
@@ -534,19 +621,20 @@ def build_suffix_array(
 
     # the device's share: histogram pre-pass, pipeline, outputs on the host
     with span("build.device", timers, "t_device_s"):
-        ih, il, statmat, slots = run(exact_cap() if cfg.adaptive else None)
+        ih, il, statmat, named, slots = run(
+            exact_cap() if cfg.adaptive else None)
         dropped = int(statmat[:, 6].sum())
         if dropped:
             # the static capacity overflowed: a short suffix array is never
             # returned.  The exact capacity drops nothing by construction.
             with span("build.rebuild"):
-                ih, il, statmat, more = run(exact_cap())
+                ih, il, statmat, named, more = run(exact_cap())
             slots += more
             if int(statmat[:, 6].sum()):
                 raise RuntimeError(
                     "shuffle dropped records at the exact capacity")
     with span("build.finalize"):
-        res = _finalize(ih, il, statmat, corpus, cfg)
+        res = _finalize(ih, il, statmat, corpus, cfg, *named)
     res.stats.update(timers)
     # the all_to_all's record slots over every attempt, and the valid
     # records the last one delivered (== num_suffixes)
@@ -559,15 +647,18 @@ def build_suffix_array(
     return res
 
 
-def _finalize(ih, il, statmat, corpus, cfg: SAConfig) -> SAResult:
+def _finalize(ih, il, statmat, corpus, cfg: SAConfig,
+              lcp_dev=None) -> SAResult:
     d = statmat.shape[0]
     per_dev = ih.shape[0] // d
-    chunks = []
-    for i in range(d):
-        lo = i * per_dev
-        cnt = int(statmat[i, 0])
-        chunks.append(global_index(ih[lo : lo + cnt], il[lo : lo + cnt]))
+    valid = [slice(i * per_dev, i * per_dev + int(statmat[i, 0]))
+             for i in range(d)]
+    chunks = [global_index(ih[v], il[v]) for v in valid]
     sa = np.concatenate(chunks) if chunks else np.zeros((0,), np.int64)
+    lcp = None
+    if lcp_dev is not None:
+        lcp = np.concatenate([lcp_dev[v] for v in valid]).astype(np.int64)
+        lcp[:1] = 0
 
     corpus = np.asarray(corpus)
     tb = token_bytes(cfg.vocab_size)
@@ -594,7 +685,9 @@ def _finalize(ih, il, statmat, corpus, cfg: SAConfig) -> SAResult:
         "retries": int(statmat[:, 7].sum()),
         "unresolved": int(statmat[:, 8].sum()),
     }
-    return SAResult(suffix_array=sa, footprint=fp, stats=stats)
+    if lcp is not None:
+        stats["lcp_device_pairs"] = int((lcp[1:] != LCP_UNNAMED).sum())
+    return SAResult(suffix_array=sa, footprint=fp, stats=stats, lcp=lcp)
 
 
 # ---------------------------------------------------------------------------

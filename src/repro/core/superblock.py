@@ -68,7 +68,7 @@ import numpy as np
 from repro.config import SAConfig, SuperblockConfig
 from repro.core.integrity import CorruptionError, crc32_array, publish_file
 from repro.core.journal import JOURNAL_NAME, BuildJournal, verify_spilled_run
-from repro.core.lcp import lcp_from_sa, pairwise_lcp
+from repro.core.lcp import finish_lcp, pairwise_lcp
 from repro.core.pipeline import DeviceRefiner, build_suffix_array
 from repro.core.pipeline_exec import PipelineExecutor, pipeline_point
 from repro.core.sanitize import (
@@ -1438,14 +1438,15 @@ def _build_superblock_phases(
                             request_capacity=sb.request_capacity)
         res = build_suffix_array(
             store.stage_items(0, backend.n), lengths=lengths, cfg=cfg,
-            mesh=mesh,
+            mesh=mesh, lcp=sb.emit_lcp,
         )
-        # single-pass builds have no ordered emission to piggyback on: the
-        # LCP is recomputed post-hoc from the finished SA, and the index
-        # directory (when asked for) is written wholesale.
-        if sb.emit_lcp and res.lcp is None:
+        # the device named the LCP of every pair its refinement split; the
+        # host fetches the rest.  The index directory (when asked for) is
+        # written wholesale.
+        if sb.emit_lcp:
             with span("build.lcp", res.stats, "t_lcp_s"):
-                res.lcp = lcp_from_sa(store, res.suffix_array)
+                res.stats["lcp_host_pairs"] = finish_lcp(
+                    store, res.suffix_array, res.lcp)
             res.stats["emit_lcp"] = True
         if sb.write_manifest:
             _write_index_manifest(res, backend, cfg, sb, scratch)
@@ -1945,7 +1946,7 @@ def build_suffix_array_auto(
             corpus = _materialize_corpus(corpus, cfg)
         return build_suffix_array(corpus, lengths=lengths, cfg=cfg, mesh=mesh)
     # index finalization (LCP / manifest) always runs through the superblock
-    # wrapper: its single-block early path owns the post-hoc LCP + save.
+    # wrapper: its single-block early path owns the LCP + save.
     return build_suffix_array_superblock(
         corpus, lengths=lengths, cfg=cfg, sb=sb, mesh=mesh
     )

@@ -24,6 +24,8 @@ import numpy as np
 
 # Sentinel key value: sorts after every real key (keys are < 2^31 - 1).
 KEY_SENTINEL = np.int32(np.iinfo(np.int32).max)
+# An LCP entry the device build could not name; the host finishes it.
+LCP_UNNAMED = -1
 # int31 word size used for index packing.
 WORD_BITS = 31
 WORD_MOD = 1 << WORD_BITS
@@ -137,7 +139,9 @@ class SAResult:
     stats: dict
     # (n,) int64 adjacent-pair LCP array (lcp[i] = LCP(sa[i-1], sa[i]),
     # lcp[0] = 0) when the build was asked for it (SuperblockConfig.emit_lcp
-    # / repro.core.lcp); None otherwise
+    # / repro.core.lcp); None otherwise.  From build_suffix_array(lcp=True)
+    # the pairs the device could not name hold LCP_UNNAMED until
+    # repro.core.lcp.finish_lcp fills them.
     lcp: Optional[np.ndarray] = None
 
     def read_offset(self, stride_bits: int) -> Tuple[np.ndarray, np.ndarray]:

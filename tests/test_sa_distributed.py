@@ -143,7 +143,8 @@ def static_builds(run_multidev, tmp_path_factory):
     """One 4-device subprocess: ``SuffixArrayIndex.build`` under the static
     shuffle capacity (``adaptive=False``) at each of ``STATIC_SLACKS``,
     published to an index directory and reopened; per slack, what the
-    index holds against the plain reference and its shuffle counters."""
+    index holds against the plain reference, its shuffle counters and who
+    named its LCP."""
     import json
 
     root = tmp_path_factory.mktemp("static")
@@ -192,7 +193,8 @@ def static_builds(run_multidev, tmp_path_factory):
                     **{{k: idx.build_stats[k] for k in (
                         "num_suffixes", "dropped", "unresolved",
                         "shuffle_slots", "shuffle_records",
-                        "shuffle_dropped", "shuffle_rebuilds")}})
+                        "shuffle_dropped", "shuffle_rebuilds",
+                        "lcp_device_pairs", "lcp_host_pairs")}})
         print("RESULT " + json.dumps(out))
         """,
         devices=4, timeout=300)
@@ -225,3 +227,13 @@ def test_shuffle_counters_reach_build_stats(static_builds):
                                                 + rebuilt["exact_cap"])
     for got in (fits, rebuilt):
         assert got["shuffle_records"] == got["num_suffixes"]
+
+
+@pytest.mark.parametrize("slack", STATIC_SLACKS)
+def test_static_build_lcp_named_on_device(static_builds, slack):
+    """The four devices name every LCP pair but the three that cross a
+    device boundary, whether the shuffle fits or is rebuilt; the host
+    fetches those three."""
+    got = static_builds[str(slack)]
+    assert got["lcp_host_pairs"] == 3
+    assert got["lcp_device_pairs"] + 3 == got["num_suffixes"] - 1

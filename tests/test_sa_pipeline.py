@@ -184,6 +184,125 @@ def test_lcp_kasai_matches_naive():
         assert lcp[i] == m
 
 
+def _lcp_cases():
+    rng = np.random.default_rng(12)
+    lens = rng.integers(0, 11, size=(25,)).astype(np.int32)
+    varlen = np.zeros((25, 11), np.int32)
+    for i, n in enumerate(lens):
+        varlen[i, :n] = rng.integers(1, 5, size=(n,))
+    one = rng.integers(1, 5, size=(1, 60)).astype(np.int32)
+    capped = np.concatenate(
+        [np.tile(one, (3, 1)), rng.integers(1, 5, size=(5, 60))]
+    ).astype(np.int32)
+    bits = SAConfig(vocab_size=4, chars_per_word=3, key_words=2,
+                    packing="bits")
+    return {
+        "uniform-reads": (rng.integers(1, 5, size=(60, 15)), None, CFG_DNA),
+        "uniform-reads-bits": (rng.integers(1, 5, size=(40, 13)), None, bits),
+        "varlen-reads": (varlen, lens, CFG_DNA),
+        "text": (rng.integers(1, 5, size=(300,)), None, CFG_DNA),
+        "atat-text": (np.tile(np.array([1, 4]), 120), None, CFG_DNA),
+        "duplicate-reads": (
+            np.tile(rng.integers(1, 5, size=(4, 9)), (4, 1)), None, CFG_DNA),
+        # identical 60-token reads stay tied past the round cap
+        "round-cap": (capped, None, SAConfig(
+            vocab_size=4, chars_per_word=2, key_words=2, max_rounds=1)),
+    }
+
+
+def _as_text(corpus, lens, sa):
+    """The corpus as one text and the SA as positions in it: each read
+    ends in a separator below every token and below later reads' ones, so
+    the order and the LCP are the read set's."""
+    if corpus.ndim == 1:
+        return corpus, sa
+    r, l = corpus.shape
+    lens = np.full(r, l) if lens is None else np.asarray(lens)
+    stride = int(np.ceil(np.log2(l + 1)))
+    start = np.concatenate([[0], np.cumsum(lens + 1)[:-1]])
+    text = np.concatenate(
+        [np.append(corpus[i, : lens[i]], i - r) for i in range(r)])
+    return text, start[sa >> stride] + (sa & ((1 << stride) - 1))
+
+
+@pytest.mark.parametrize("case", list(_lcp_cases()))
+def test_device_lcp_matches_kasai(case):
+    """The LCP the device names, finished on the host, is the reference's;
+    the device names every pair but those it cannot see."""
+    from repro.core.lcp import finish_lcp, lcp_from_sa
+    from repro.core.store import CorpusStore
+    from repro.core.types import LCP_UNNAMED
+
+    corpus, lens, cfg = _lcp_cases()[case]
+    corpus = np.asarray(corpus, np.int32)
+    res = build_suffix_array(corpus, lengths=lens, cfg=cfg, lcp=True)
+    sa, n = res.suffix_array, res.suffix_array.shape[0]
+    if corpus.ndim == 1:
+        np.testing.assert_array_equal(sa, naive_sa_text(corpus))
+    else:
+        np.testing.assert_array_equal(sa, naive_sa_reads(corpus, lens))
+    assert res.lcp.dtype == np.int64 and res.lcp.shape == (n,)
+    unnamed = int((res.lcp[1:] == LCP_UNNAMED).sum())
+    store = CorpusStore(corpus, cfg)
+    host = finish_lcp(store, sa, res.lcp)
+    assert host == unnamed
+    assert res.stats["lcp_device_pairs"] + host == n - 1
+    np.testing.assert_array_equal(res.lcp, lcp_kasai(*_as_text(corpus, lens,
+                                                                sa)))
+    np.testing.assert_array_equal(res.lcp, lcp_from_sa(store, sa))
+    if case == "round-cap":
+        assert res.stats["unresolved"] > 0 and host > 0
+    elif case in ("varlen-reads", "duplicate-reads"):
+        # identical suffixes: by fetches where lengths vary, else by length
+        assert (host > 0) == (case == "varlen-reads")
+    else:
+        assert host == 0
+
+
+@pytest.mark.parametrize("merge_backend", ["host", "device"])
+def test_out_of_core_blocks_compile_without_lcp(monkeypatch, merge_backend):
+    """An out-of-core build's blocks and its device refiner never carry the
+    LCP (the merge's sink computes it): their programs are the ones traced
+    without it, and the published LCP is still the reference's."""
+    from repro.config import SuperblockConfig
+    from repro.core import pipeline, superblock
+
+    flags, loops, block_stats = [], [], []
+    make, refine = pipeline.make_pipeline, pipeline._refine_tie_groups
+    build = superblock.build_suffix_array
+
+    def spy_make(*a, **kw):
+        flags.append(kw.get("lcp", False))
+        return make(*a, **kw)
+
+    def spy_refine(*a, **kw):
+        loops.append(kw.get("lcp"))
+        return refine(*a, **kw)
+
+    def spy_build(*a, **kw):
+        res = build(*a, **kw)
+        block_stats.append(res.stats)
+        return res
+
+    monkeypatch.setattr(pipeline, "make_pipeline", spy_make)
+    monkeypatch.setattr(pipeline, "_refine_tie_groups", spy_refine)
+    monkeypatch.setattr(superblock, "build_suffix_array", spy_build)
+    reads = np.random.default_rng(13).integers(1, 5, size=(48, 12))
+    reads = reads.astype(np.int32)
+    sb = SuperblockConfig(num_superblocks=3, emit_lcp=True,
+                          merge_backend=merge_backend)
+    res = superblock.build_suffix_array_superblock(reads, cfg=CFG_DNA, sb=sb)
+    assert len(block_stats) == 3 and flags == [False] * 3
+    assert all("lcp_device_pairs" not in s for s in [*block_stats, res.stats])
+    assert all(x is None for x in loops)
+    if merge_backend == "device":
+        assert loops  # the refiner traced its loop here, without the LCP
+    np.testing.assert_array_equal(res.suffix_array, naive_sa_reads(reads))
+    np.testing.assert_array_equal(
+        np.asarray(res.lcp),
+        lcp_kasai(*_as_text(reads, None, res.suffix_array)))
+
+
 # ---------------------------------------------------------------------------
 # property tests
 # ---------------------------------------------------------------------------
