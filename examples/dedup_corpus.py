@@ -1,8 +1,9 @@
 """LM data pipeline: exact-substring dedup of a token corpus via the
 distributed suffix array (the paper's pipeline as an LLM-data substrate).
 
-Plants duplicate spans in a synthetic corpus, finds them with SA+LCP, masks
-them from the training loss, and shows the loader consuming the mask.
+Plants duplicate spans in a synthetic corpus, finds them with SA+LCP, and
+returns a keep mask that drops the later occurrences of each duplicated
+span.
 
     PYTHONPATH=src python examples/dedup_corpus.py
 """
@@ -11,7 +12,6 @@ import numpy as np
 from repro.config import SAConfig
 from repro.data.corpus import synth_token_corpus
 from repro.data.dedup import dedup_corpus
-from repro.data.loader import DeterministicLoader
 
 VOCAB = 255
 tokens, planted = synth_token_corpus(
@@ -34,9 +34,3 @@ for src, dst, span in planted:
             missed += 1
 assert missed == 0, f"{missed} duplicate pairs fully survived dedup"
 print("no duplicate pair survives twice: True")
-
-loader = DeterministicLoader(tokens, batch=4, seq_len=128, seed=0,
-                             mask=keep.astype(np.float32))
-batch = loader.batch_at(0)
-print(f"loader batch: tokens {batch['tokens'].shape}, "
-      f"mask coverage {batch['mask'].mean():.3f}")

@@ -1,41 +1,4 @@
-"""Configuration system for the repro framework.
+"""Configuration of the suffix-array system: plain frozen dataclasses."""
+from repro.config.base import SAConfig, SuperblockConfig, asdict, replace
 
-Plain dataclasses (no external deps) with a registry keyed by ``--arch`` id.
-"""
-from repro.config.base import (
-    ArchConfig,
-    AttentionConfig,
-    MeshConfig,
-    MoEConfig,
-    SAConfig,
-    ServeConfig,
-    ShapeConfig,
-    ShardingPolicy,
-    SSMConfig,
-    SuperblockConfig,
-    TrainConfig,
-    LM_SHAPES,
-    asdict,
-    replace,
-)
-from repro.config.registry import get_arch, list_archs, register_arch
-
-__all__ = [
-    "ArchConfig",
-    "AttentionConfig",
-    "MeshConfig",
-    "MoEConfig",
-    "SAConfig",
-    "ServeConfig",
-    "ShapeConfig",
-    "ShardingPolicy",
-    "SSMConfig",
-    "SuperblockConfig",
-    "TrainConfig",
-    "LM_SHAPES",
-    "asdict",
-    "replace",
-    "get_arch",
-    "list_archs",
-    "register_arch",
-]
+__all__ = ["SAConfig", "SuperblockConfig", "asdict", "replace"]

@@ -1,249 +1,14 @@
-"""Core configuration dataclasses.
+"""Configuration dataclasses of the suffix-array system.
 
-Every architecture in ``repro/configs`` instantiates :class:`ArchConfig`; the
-suffix-array pipeline is configured by :class:`SAConfig`.  All fields are plain
-python values so configs can be serialized with msgpack/json for checkpoint
-metadata.
+:class:`SAConfig` configures the device pipeline and :class:`SuperblockConfig`
+the out-of-core build around it.  All fields are plain python values so a
+config serializes to json (the index manifest records the ``SAConfig``).
 """
 from __future__ import annotations
 
 import dataclasses
-import math
-from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Sequence, Tuple
-
-
-# ---------------------------------------------------------------------------
-# Model configuration
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class AttentionConfig:
-    """Attention block configuration (GQA/MQA/SWA/local:global)."""
-
-    num_heads: int
-    num_kv_heads: int
-    head_dim: int
-    rope_theta: float = 10_000.0
-    # Sliding window size for *local* layers. ``None`` => full attention.
-    sliding_window: Optional[int] = None
-    # Pattern of local (``L``) / global (``G``) layers, tiled over depth.
-    # ``"G"`` => all-global; gemma3 uses ``"LLLLLG"`` (5:1).
-    layer_pattern: str = "G"
-    # Soft cap on attention logits (gemma-style); 0 disables.
-    logit_softcap: float = 0.0
-    qk_norm: bool = False
-
-    def window_for_layer(self, layer: int, seq_len: int) -> int:
-        """Effective window for ``layer`` (full == seq_len)."""
-        kind = self.layer_pattern[layer % len(self.layer_pattern)]
-        if kind == "L" and self.sliding_window is not None:
-            return min(self.sliding_window, seq_len)
-        return seq_len
-
-    def is_global_layer(self, layer: int) -> bool:
-        return self.layer_pattern[layer % len(self.layer_pattern)] == "G" or (
-            self.sliding_window is None
-        )
-
-
-@dataclass(frozen=True)
-class MoEConfig:
-    """Mixture-of-experts configuration (None on dense archs)."""
-
-    num_experts: int
-    top_k: int
-    expert_ffn_dim: int
-    capacity_factor: float = 1.25
-    router_jitter: float = 0.0
-    # "tp"  : shard each expert's ffn dim over the model axis (always legal)
-    # "ep"  : shard the expert dim over the model axis (needs divisibility or
-    #          accepts GSPMD padding)
-    sharding: str = "tp"
-
-
-@dataclass(frozen=True)
-class SSMConfig:
-    """State-space / recurrent block configuration (xLSTM, Mamba-style)."""
-
-    state_dim: int = 16
-    conv_width: int = 4
-    expand: int = 2
-    # xlstm: pattern of "m" (mLSTM) / "s" (sLSTM) blocks tiled over depth.
-    block_pattern: str = "m"
-    chunk_size: int = 64
-
-
-@dataclass(frozen=True)
-class ArchConfig:
-    """One assigned architecture."""
-
-    name: str
-    family: str  # dense | moe | ssm | hybrid | audio | vlm
-    num_layers: int
-    d_model: int
-    d_ff: int
-    vocab_size: int
-    attention: Optional[AttentionConfig]
-    moe: Optional[MoEConfig] = None
-    ssm: Optional[SSMConfig] = None
-    # tokens | embeddings (audio/vlm frontends feed precomputed embeddings)
-    input_mode: str = "tokens"
-    tie_embeddings: bool = True
-    norm_eps: float = 1e-6
-    act: str = "silu"  # silu => SwiGLU, gelu => GeGLU-less plain MLP
-    param_dtype: str = "bfloat16"
-    compute_dtype: str = "bfloat16"
-    # lax.scan over stacked layers (compact HLO) vs python unroll
-    scan_layers: bool = True
-    remat: str = "nothing_saveable"  # none | nothing_saveable | dots_saveable
-    # ---- perf features (§Perf hillclimb knobs) ---------------------------
-    # sequence-chunked cross entropy: never materialize full (B,S,V) logits
-    loss_chunk: int = 0  # 0 = off
-    # flash-style online-softmax attention over KV blocks (no S x S scores)
-    attn_chunk: int = 0  # 0 = off
-    # decode caches sized to each layer's window (local:global aware)
-    window_decode_cache: bool = False
-    # source provenance string from the assignment table
-    source: str = ""
-    notes: str = ""
-
-    # -- derived -----------------------------------------------------------
-    def qkv_dims(self) -> Tuple[int, int]:
-        a = self.attention
-        return a.num_heads * a.head_dim, a.num_kv_heads * a.head_dim
-
-    def param_count(self) -> int:
-        """Total parameter count (used for 6ND model-flops)."""
-        d, l, v = self.d_model, self.num_layers, self.vocab_size
-        total = v * d  # embedding
-        if not self.tie_embeddings:
-            total += v * d
-        per_layer = 0
-        if self.attention is not None:
-            q, kv = self.qkv_dims()
-            per_layer += d * q + 2 * d * kv + q * d  # q,k,v,o
-        if self.moe is not None:
-            per_layer += d * self.moe.num_experts  # router
-            per_layer += self.moe.num_experts * 3 * d * self.moe.expert_ffn_dim
-        elif self.d_ff > 0:
-            n_mat = 3 if self.act == "silu" else 2
-            per_layer += n_mat * d * self.d_ff
-        if self.ssm is not None:
-            s = self.ssm
-            inner = s.expand * d
-            # in_proj (x and z), dt/B/C projections, out_proj, conv
-            per_layer += d * 2 * inner + inner * (2 * s.state_dim + 1) + inner * d
-            per_layer += inner * s.conv_width
-        per_layer += 2 * d  # norms
-        return total + l * per_layer
-
-    def active_param_count(self) -> int:
-        """Active parameters per token (MoE: only top_k experts count)."""
-        if self.moe is None:
-            return self.param_count()
-        m = self.moe
-        inactive = (m.num_experts - m.top_k) * 3 * self.d_model * m.expert_ffn_dim
-        return self.param_count() - self.num_layers * inactive
-
-
-# ---------------------------------------------------------------------------
-# Shapes (assigned input-shape set)
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # train | prefill | decode
-
-
-LM_SHAPES: Dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
-
-
-# ---------------------------------------------------------------------------
-# Mesh / sharding
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class MeshConfig:
-    shape: Tuple[int, ...] = (16, 16)
-    axis_names: Tuple[str, ...] = ("data", "model")
-
-    @property
-    def num_devices(self) -> int:
-        return int(math.prod(self.shape))
-
-    @property
-    def data_axes(self) -> Tuple[str, ...]:
-        return tuple(a for a in self.axis_names if a in ("pod", "data"))
-
-    @property
-    def model_axes(self) -> Tuple[str, ...]:
-        return tuple(a for a in self.axis_names if a == "model")
-
-
-@dataclass(frozen=True)
-class ShardingPolicy:
-    """How logical axes map onto mesh axes (with divisibility fallback)."""
-
-    fsdp_axes: Tuple[str, ...] = ("data",)
-    tp_axes: Tuple[str, ...] = ("model",)
-    dp_axes: Tuple[str, ...] = ("pod", "data")
-    # shard decode KV cache sequence dim over these axes (flash-decoding style)
-    kv_seq_axes: Tuple[str, ...] = ("model",)
-    # activations sequence-parallel axes for training (None = off)
-    seq_axes: Tuple[str, ...] = ()
-    moe_ep: bool = False
-    # gradient reduction: "reduce_scatter" (fsdp) or "all_reduce"
-    grad_reduce: str = "reduce_scatter"
-    # FSDP-shard the embedding table's d_model dim.  False keeps the table
-    # TP-sharded on vocab only, so the logits contraction never sums over a
-    # sharded d_model — avoids a (B,S,V) all-reduce over the data axis
-    # (§Perf: the minicpm/gemma3 prefill collective pathology).
-    embed_fsdp: bool = True
-
-
-# ---------------------------------------------------------------------------
-# Training / serving
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class TrainConfig:
-    learning_rate: float = 3e-4
-    weight_decay: float = 0.1
-    beta1: float = 0.9
-    beta2: float = 0.95
-    eps: float = 1e-8
-    grad_clip: float = 1.0
-    schedule: str = "cosine"  # cosine | wsd | constant
-    warmup_steps: int = 100
-    decay_steps: int = 10_000
-    stable_steps: int = 0  # for WSD
-    min_lr_ratio: float = 0.1
-    microbatches: int = 1
-    # gradient compression across DP replicas: none | int8 | topk
-    grad_compression: str = "none"
-    topk_ratio: float = 0.05
-    seed: int = 0
-
-
-@dataclass(frozen=True)
-class ServeConfig:
-    max_seq_len: int = 32_768
-    max_batch: int = 128
-    prefill_chunk: int = 512
-    eos_id: int = 2
+from dataclasses import dataclass
+from typing import Any, Dict, Optional
 
 
 # ---------------------------------------------------------------------------
@@ -335,7 +100,7 @@ class SuperblockConfig:
         (``kernels/merge_path`` Pallas kernel under ``cfg.use_pallas``, the
         numpy ``CorpusStore.rank_windows`` reference otherwise).  No host
         heap walk — store round-trips collapse by the tile width (>= 5x
-        fewer than ``kway``, asserted in tests + ``benchmarks.run merge``).
+        fewer than ``kway``, asserted in ``tests/test_merge_path.py``).
       * ``"kway"`` — the PR-2 path: splitter ranks located inside each
         sorted run by O(log n) binary-search store comparisons, runs k-way
         merged at run heads through a host heap, windows fetched to
@@ -343,7 +108,7 @@ class SuperblockConfig:
         set).  Kept as the round-trip reference.
       * ``"rerank"`` — the PR-1 baseline: every bucket is re-ranked from
         scratch by the group-synchronous refinement loop.  Kept as the
-        merge-traffic reference (``benchmarks.run superblock``).
+        merge-traffic reference (``tests/test_superblock.py``).
     ``merge_tile``: merge-path output-tile width (buffered run heads per
       run); 0 derives it — ``capacity_records // num_runs`` capped at 4096,
       or the frontier read-ahead budget in streaming builds.

@@ -9,15 +9,14 @@ Three small, widely-shared pieces that make the out-of-core build crash-safe
   same corrupt bytes (or, worse, a different wrong answer), so no retry
   layer may catch it.  :class:`TransientError` is the opposite contract —
   a fault that *may* succeed on retry (an injected store fault, a flaky
-  remote read).  ``runtime.fault.TransientFault`` and the store layer's
+  remote read).  :func:`retry_step` and the store layer's
   :class:`~repro.core.store.RetryingBackend` share this split so a
   corruption can never be masked by a retry loop.
 
 * **Checksums.**  Thin stdlib ``zlib.crc32`` helpers over bytes, arrays and
   files.  crc32 is not cryptographic — the threat model is torn writes,
   truncation and bit rot, not adversaries — and it is cheap enough to leave
-  on by default (the ``benchmarks.run build`` integrity section gates the
-  overhead under 5%).
+  on by default.
 
 * **Atomic publish.**  ``tmp + os.replace`` alone does not survive power
   loss: the rename itself lives in the directory, and the directory entry
@@ -28,9 +27,11 @@ Three small, widely-shared pieces that make the out-of-core build crash-safe
 """
 from __future__ import annotations
 
+import logging
 import os
+import time
 import zlib
-from typing import Optional
+from typing import Callable, Optional, Tuple, Type
 
 import numpy as np
 
@@ -38,7 +39,9 @@ __all__ = [
     "CorruptionError",
     "TransientError",
     "TransientStoreError",
+    "TransientFault",
     "DEFAULT_RETRYABLE",
+    "retry_step",
     "crc32_bytes",
     "crc32_array",
     "crc32_file",
@@ -78,7 +81,7 @@ class CorruptionError(Exception):
 class TransientError(RuntimeError):
     """A fault that may succeed on retry (network blip, injected fault).
 
-    The shared base of ``runtime.fault.TransientFault`` and
+    The shared base of :class:`TransientFault` and
     :class:`TransientStoreError`; the default ``retryable`` allowlist of the
     retry layers.
     """
@@ -88,8 +91,46 @@ class TransientStoreError(TransientError):
     """Transient fault raised from a store backend read/gather."""
 
 
+class TransientFault(TransientError):
+    """Transient fault of a whole step (a lost worker), retried by
+    :func:`retry_step`."""
+
+
 # what retry layers retry unless told otherwise
 DEFAULT_RETRYABLE = (TransientError,)
+
+log = logging.getLogger("repro.fault")
+
+
+def retry_step(
+    fn: Callable,
+    *args,
+    retries: int = 3,
+    backoff: float = 0.05,
+    on_retry: Optional[Callable[[int, Exception], None]] = None,
+    retryable: Tuple[Type[BaseException], ...] = (Exception,),
+):
+    """Run ``fn`` with transactional retry; re-raises after ``retries``.
+
+    ``retryable`` narrows which exceptions are retried (default keeps the
+    historical catch-all boundary).  :class:`CorruptionError` is always
+    fatal: it propagates immediately even when the allowlist would match.
+    """
+    err: Optional[Exception] = None
+    for attempt in range(retries + 1):
+        try:
+            return fn(*args)
+        except CorruptionError:
+            raise  # corrupt bytes stay corrupt — retrying masks the fault
+        except retryable as e:  # noqa: BLE001 — deliberate retry boundary
+            err = e
+            if attempt == retries:
+                break
+            if on_retry is not None:
+                on_retry(attempt, e)
+            log.warning("step failed (attempt %d): %s — retrying", attempt + 1, e)
+            time.sleep(backoff * (2**attempt))
+    raise err  # type: ignore[misc]
 
 
 # ---------------------------------------------------------------------------

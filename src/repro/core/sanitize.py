@@ -173,7 +173,7 @@ class SanitizingSink:
     order (:func:`lex_less_rows` over packed key windows, ties by global
     index).  Fetches go through a private audit :class:`CorpusStore` over
     the same backend, so the build's own request/byte counters (asserted
-    by the traffic-gate benchmarks) are untouched.
+    by the traffic-gate tests) are untouched.
     """
 
     def __init__(self, sink, backend: StoreBackend, cfg: SAConfig,

@@ -445,8 +445,7 @@ class ChunkedFileBackend(StoreBackend):
         from repro.data.chunk_store import ChunkedCorpusReader
 
         # every chunk the LRU caches is crc-checked on load (v2 files);
-        # the overhead is gated <5% by the benchmarks.run build integrity
-        # section, so verification defaults on.
+        # crc32 is cheap next to the read, so verification defaults on.
         self._reader = ChunkedCorpusReader(path, verify=verify)
         meta = self._reader.meta
         self._init_geometry(meta.text_mode, meta.items, meta.row_len, cfg)
@@ -546,12 +545,11 @@ class ThrottledBackend(StoreBackend):
 
     Adds a fixed ``time.sleep`` to every ``gather`` and ``read_items`` call,
     simulating the paper's network/disk tier with a latency that does not
-    depend on machine load — which is what makes the pipelined-vs-synchronous
-    build benchmark (``benchmarks.run build``) reproducible in CI: the sleep
-    releases the GIL, so the overlap the pipeline claims is genuine overlap,
-    and the measured speedup is a property of the schedule, not of the host's
-    momentary disk speed.  Geometry and counters delegate to the wrapped
-    backend; accounting semantics are unchanged.
+    depend on machine load: the sleep releases the GIL, so a pipelined build
+    over it overlaps genuinely, and its speedup against a synchronous build
+    is a property of the schedule, not of the host's momentary disk speed.
+    Geometry and counters delegate to the wrapped backend; accounting
+    semantics are unchanged.
     """
 
     def __init__(self, inner: StoreBackend, gather_delay_s: float = 0.0,
@@ -662,8 +660,8 @@ class RetryingBackend(StoreBackend):
 
 class FlakyBackend(StoreBackend):
     """Deterministic fault injector: scripted transient failures on backend
-    reads/gathers (the chaos-harness counterpart of ``runtime.fault``'s
-    step-level :class:`FaultInjector`).
+    reads/gathers (the chaos harness's store faults,
+    ``tests/test_crash_resume.py``).
 
     Failure ordinals count *successful* pass-throughs: an injected failure
     does not advance the ordinal, so a retried call fails

@@ -31,7 +31,7 @@ Phases (see :func:`build_suffix_array_superblock`):
    ``cfg.use_pallas``, its numpy reference otherwise).  No host loop touches
    individual suffixes: *indexes move, tokens stay put*, and store
    round-trips collapse by the tile width (>= 5x fewer than the heap walk,
-   asserted in tests and ``benchmarks.run merge``).  Text mode first splits
+   asserted in ``tests/test_merge_path.py``).  Text mode first splits
    off the block-tail *risk set* (suffixes whose block-local comparisons
    could have run past the block boundary) and re-ranks only those; the
    re-ranked pieces join the tile merge as runs of their own.
@@ -1788,7 +1788,7 @@ def _merge_phase(store, backend, cfg, sb, plan, local_sas, block_stats, *,
         sink = _JournalingSink(sink, jr)  # emitted-rows watermark records
     if sanitize_enabled(sb):
         # order-verify emitted pieces through a private audit store: the
-        # build store's traffic counters (gated by benchmarks) stay clean.
+        # build store's traffic counters (gated by tests) stay clean.
         sink = SanitizingSink(sink, backend, cfg,
                               request_capacity=sb.request_capacity)
     peak_candidates = 0

@@ -4,9 +4,9 @@
 fixed-width padded record and the *whole payload* rides the shuffle — the
 behaviour whose local-disk analogue breaks the paper's Table III Case 5.
 On our mesh the disk pressure becomes shuffle/HBM pressure: record width is
-(L+1) tokens + index vs the scheme's constant 16 bytes, and the footprint
-tables in ``benchmarks/`` reproduce the paper's ratios from these two
-implementations.
+(L+1) tokens + index vs the scheme's constant 16 bytes; the ``Footprint``
+of the two implementations gives the paper's ratios
+(``examples/sa_build.py``).
 
 Reads mode only (the paper's case); long-text suffixes are unbounded and
 cannot be materialized at fixed width — which is itself the point.

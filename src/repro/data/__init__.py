@@ -9,14 +9,12 @@ from repro.data.chunk_store import (
 )
 from repro.data.corpus import synth_dna_reads, synth_token_corpus
 from repro.data.dedup import dedup_corpus, find_duplicate_spans
-from repro.data.loader import DeterministicLoader
 
 __all__ = [
     "synth_dna_reads",
     "synth_token_corpus",
     "dedup_corpus",
     "find_duplicate_spans",
-    "DeterministicLoader",
     "ChunkedCorpusMeta",
     "ChunkedCorpusReader",
     "chunk_items_for_budget",

@@ -2,198 +2,23 @@ import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
-"""Multi-pod dry-run: lower + compile every (architecture x input-shape x
-mesh) cell against ShapeDtypeStruct inputs; record memory_analysis,
-cost_analysis and HLO collective bytes for the roofline (EXPERIMENTS.md).
+"""Multi-pod dry-run of the suffix-array pipeline: lower + compile
+``make_pipeline`` on a flat 256- or 512-device mesh of host devices against
+ShapeDtypeStruct inputs; record cost_analysis, memory_analysis and HLO
+collective bytes.
 
-The two XLA_FLAGS lines above MUST stay the first statements — jax locks the
+The XLA_FLAGS lines above MUST stay the first statements — jax locks the
 device count at first init.  Never set this in conftest/pyproject.
 
 Usage:
-  PYTHONPATH=src python -m repro.launch.dryrun --arch mixtral-8x7b \
-      --shape train_4k --mesh single
-  PYTHONPATH=src python -m repro.launch.dryrun --all --out dryrun.json
-  PYTHONPATH=src python -m repro.launch.dryrun --sa   # SA-pipeline dry-run
+  PYTHONPATH=src python -m repro.launch.dryrun --mesh single --out dryrun.json
 """
 import argparse
-import contextlib
 import json
-import sys
 import time
-import traceback
 
 import jax
 import numpy as np
-
-
-def run_cell(arch: str, shape_name: str, multi_pod: bool, record_hlo: bool = True,
-             cfg_override=None, policy_override=None):
-    """Lower+compile one cell; returns a result dict."""
-    from repro.analysis import hlo as hlo_lib
-    from repro.analysis import roofline as rl
-    from repro.config import LM_SHAPES, ShardingPolicy, TrainConfig, get_arch
-    from repro.launch.mesh import make_production_mesh
-    from repro.launch.specs import (
-        input_specs,
-        long_context_supported,
-        train_state_specs,
-    )
-    from repro.models.model import Model
-    from repro.sharding.rules import batch_specs
-    from repro.train.step import make_decode_step, make_prefill_step, make_train_step
-
-    cfg = get_arch(arch)
-    if cfg_override is not None:
-        cfg = cfg_override(cfg)
-    shape = LM_SHAPES[shape_name]
-    mesh_name = "2x16x16" if multi_pod else "16x16"
-    t0 = time.time()
-
-    if shape.name == "long_500k" and not long_context_supported(cfg):
-        return {
-            "arch": arch, "shape": shape_name, "mesh": mesh_name,
-            "status": "skipped",
-            "reason": "pure full attention (DESIGN.md §5 long_500k policy)",
-        }
-
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    policy = ShardingPolicy(
-        fsdp_axes=("data",) if not multi_pod else ("pod", "data"),
-        dp_axes=("pod", "data"),
-    )
-    if policy_override is not None:
-        policy = policy_override
-    model = Model(cfg)
-    ins = input_specs(cfg, shape)
-
-    if shape.kind == "train":
-        step, state_sh, batch_sh = make_train_step(
-            model, mesh, policy, TrainConfig(), shape.global_batch, shape.seq_len
-        )
-        state = train_state_specs(model)
-        lowered = step.lower(state, ins)
-    elif shape.kind == "prefill":
-        step, param_sh, batch_sh = make_prefill_step(
-            model, mesh, policy, shape.global_batch, shape.seq_len
-        )
-        lowered = step.lower(model.abstract(), ins)
-    else:  # decode
-        step, param_sh, cache_sh, _ = make_decode_step(
-            model, mesh, policy, shape.global_batch, shape.seq_len,
-            long_context=(shape.name == "long_500k"),
-        )
-        cache = model.abstract_cache(shape.global_batch, shape.seq_len)
-        lowered = step.lower(model.abstract(), cache, ins["tokens"], ins["pos"])
-
-    compiled = lowered.compile()
-    mem = compiled.memory_analysis()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    coll = (
-        hlo_lib.collective_bytes(compiled.as_text()) if record_hlo else {}
-    )
-
-    rec = rl.Roofline(
-        arch=arch,
-        shape=shape_name,
-        mesh=mesh_name,
-        chips=int(np.prod(mesh.devices.shape)),
-        hlo_flops=float(cost.get("flops", 0.0)),
-        hlo_bytes=float(cost.get("bytes accessed", 0.0)),
-        collective=coll,
-        model_flops_total=rl.model_flops(cfg, shape),
-    ).finish()
-    with contextlib.suppress(Exception):
-        peak = getattr(mem, "peak_memory_in_bytes", None)
-        if peak is None:
-            peak = (
-                getattr(mem, "argument_size_in_bytes", 0)
-                + getattr(mem, "output_size_in_bytes", 0)
-                + getattr(mem, "temp_size_in_bytes", 0)
-            )
-        rec.peak_memory_bytes = float(peak)
-
-    out = rec.to_dict()
-    out.update(
-        status="ok",
-        seconds=round(time.time() - t0, 1),
-        roofline_fraction=rec.roofline_fraction(),
-        memory_analysis=str(mem),
-        num_params=model.num_params(),
-    )
-    return out
-
-
-def run_cell_corrected(arch: str, shape_name: str, multi_pod: bool = False,
-                       cfg_override=None, policy_override=None):
-    """Scan-once-corrected cell: combine L=1 and L=2 unrolled lowerings.
-
-    See repro.analysis.corrected — HloCostAnalysis visits scan bodies once,
-    so the production scan-over-layers program underreports; the two-point
-    unrolled lowering recovers exact per-layer costs in seconds.
-    """
-    from repro.analysis import corrected as corr
-    from repro.analysis import roofline as rl
-    from repro.config import LM_SHAPES, get_arch
-
-    base_cfg = get_arch(arch)
-    if cfg_override is not None:
-        base_cfg = cfg_override(base_cfg)
-    shape = LM_SHAPES[shape_name]
-    l = base_cfg.num_layers
-
-    if base_cfg.family == "ssm":
-        # time-scan: use raw lowering + analytic FLOPs (see corrected.py)
-        r = run_cell(arch, shape_name, multi_pod, cfg_override=cfg_override,
-                     policy_override=policy_override)
-        if r["status"] != "ok":
-            return r
-        r["hlo_flops_analytic"] = corr.xlstm_analytic_flops(base_cfg, shape)
-        r["correction"] = "xlstm-analytic-flops"
-        rec = rl.Roofline(
-            arch=arch, shape=shape_name, mesh=r["mesh"], chips=r["chips"],
-            hlo_flops=r["hlo_flops_analytic"] / r["chips"],
-            hlo_bytes=r["hlo_bytes"], collective=r["collective"],
-            model_flops_total=r["model_flops_total"],
-        ).finish()
-        r.update(rec.to_dict(), roofline_fraction=rec.roofline_fraction(),
-                 status="ok")
-        return r
-
-    sub = {}
-    for k in (1, 2):
-        ov = (lambda c, k=k: corr.reduced_arch(
-            cfg_override(c) if cfg_override else c, k))
-        r = run_cell(arch, shape_name, multi_pod, cfg_override=ov,
-                     policy_override=policy_override)
-        if r["status"] != "ok":
-            return r
-        sub[k] = r
-
-    keys = ("hlo_flops", "hlo_bytes")
-    fixed = corr.two_point(
-        {k: sub[1][k] for k in keys}, {k: sub[2][k] for k in keys}, l
-    )
-    coll = corr.two_point(sub[1]["collective"], sub[2]["collective"], l)
-    coll = {k: int(max(v, 0)) for k, v in coll.items()}
-    cfg = base_cfg
-    rec = rl.Roofline(
-        arch=arch, shape=shape_name, mesh=sub[1]["mesh"], chips=sub[1]["chips"],
-        hlo_flops=fixed["hlo_flops"], hlo_bytes=fixed["hlo_bytes"],
-        collective=coll, model_flops_total=rl.model_flops(cfg, shape),
-    ).finish()
-    out = rec.to_dict()
-    out.update(
-        status="ok",
-        correction="two-point-unrolled",
-        roofline_fraction=rec.roofline_fraction(),
-        seconds=sub[1]["seconds"] + sub[2]["seconds"],
-        num_params=None,
-        peak_memory_bytes_L2=sub[2].get("peak_memory_bytes"),
-        memory_analysis_L2=sub[2].get("memory_analysis"),
-    )
-    return out
 
 
 def run_sa_dryrun(multi_pod: bool):
@@ -238,68 +63,25 @@ def run_sa_dryrun(multi_pod: bool):
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
-    ap.add_argument("--shape", default=None)
+    ap = argparse.ArgumentParser(
+        description="Lower and compile the suffix-array pipeline on a flat "
+        "256- (single) or 512-device (multi) mesh of host devices.")
     ap.add_argument("--mesh", choices=["single", "multi", "both"], default="both")
-    ap.add_argument("--all", action="store_true")
-    ap.add_argument("--sa", action="store_true", help="SA-pipeline dry-run")
-    ap.add_argument("--corrected", action="store_true",
-                    help="scan-once-corrected roofline accounting (L=1/2)")
     ap.add_argument("--out", default="dryrun_results.json")
     args = ap.parse_args()
-
-    from repro.config import LM_SHAPES, list_archs
 
     results = []
     if os.path.exists(args.out):
         with open(args.out) as f:
             results = json.load(f)
-    done = {(r["arch"], r["shape"], r["mesh"]) for r in results}
 
     meshes = {"single": [False], "multi": [True], "both": [False, True]}[args.mesh]
-
-    if args.sa:
-        for mp in meshes:
-            r = run_sa_dryrun(mp)
-            results.append(r)
-            print(json.dumps({k: r[k] for k in ("arch", "mesh", "status", "seconds")}))
-            with open(args.out, "w") as f:
-                json.dump(results, f, indent=1)
-        return
-
-    archs = [args.arch] if args.arch else list_archs()
-    shapes = [args.shape] if args.shape else list(LM_SHAPES)
-
-    for arch in archs:
-        for shape in shapes:
-            for mp in meshes:
-                mesh_name = "2x16x16" if mp else "16x16"
-                if (arch, shape, mesh_name) in done:
-                    continue
-                try:
-                    if args.corrected:
-                        r = run_cell_corrected(arch, shape, mp)
-                    else:
-                        r = run_cell(arch, shape, mp)
-                except Exception as e:  # record the failure, keep going
-                    r = {
-                        "arch": arch, "shape": shape, "mesh": mesh_name,
-                        "status": "error",
-                        "error": f"{type(e).__name__}: {e}",
-                        "trace": traceback.format_exc()[-2000:],
-                    }
-                results.append(r)
-                print(
-                    json.dumps(
-                        {k: r.get(k) for k in
-                         ("arch", "shape", "mesh", "status", "seconds",
-                          "bottleneck", "roofline_fraction", "error")}
-                    ),
-                    flush=True,
-                )
-                with open(args.out, "w") as f:
-                    json.dump(results, f, indent=1)
+    for mp in meshes:
+        r = run_sa_dryrun(mp)
+        results.append(r)
+        print(json.dumps({k: r[k] for k in ("arch", "mesh", "status", "seconds")}))
+        with open(args.out, "w") as f:
+            json.dump(results, f, indent=1)
 
 
 if __name__ == "__main__":

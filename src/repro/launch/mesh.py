@@ -1,21 +1,12 @@
-"""Production mesh definitions.
+"""Mesh for the suffix-array pipeline.
 
 A FUNCTION (not module-level constant) so importing this module never touches
-jax device state.  Single pod = 16x16 = 256 chips (v5e pod); multi-pod adds a
-leading "pod" axis (2 pods = 512 chips).  The SA pipeline flattens whatever
-mesh it is given into one shard axis (``sa_mesh``).
+jax device state.  The SA pipeline flattens whatever mesh it is given into one
+shard axis (``sa``).
 """
 from __future__ import annotations
 
 import numpy as np
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    import jax
-
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
 
 
 def make_sa_mesh(num_shards: int | None = None):
@@ -27,13 +18,3 @@ def make_sa_mesh(num_shards: int | None = None):
     if num_shards is not None:
         devs = devs[:num_shards]
     return Mesh(devs, ("sa",))
-
-
-def make_local_mesh(shape=None, axes=("data", "model")):
-    """Best-effort mesh over the locally available devices (tests/examples)."""
-    import jax
-
-    n = len(jax.devices())
-    if shape is None:
-        shape = (n, 1)
-    return jax.make_mesh(shape, axes)

@@ -1,4 +1,5 @@
-from repro.serve.engine import Request, ServeEngine
-from repro.serve.sa_engine import ShardedSAEngine, SuffixArrayIndex
+"""Query serving over a built or reopened suffix-array index.
 
-__all__ = ["Request", "ServeEngine", "ShardedSAEngine", "SuffixArrayIndex"]
+Nothing is imported eagerly: ``repro.serve.sa_engine`` holds
+``SuffixArrayIndex`` and ``ShardedSAEngine``.
+"""

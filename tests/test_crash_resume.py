@@ -24,7 +24,12 @@ import pytest
 
 import repro.core.superblock as sbmod
 from repro.config import SAConfig, SuperblockConfig
-from repro.core.integrity import CorruptionError, TransientError
+from repro.core.integrity import (
+    CorruptionError,
+    TransientError,
+    TransientFault,
+    retry_step,
+)
 from repro.core.journal import JOURNAL_NAME
 from repro.core.store import (
     ChunkedFileBackend,
@@ -34,7 +39,6 @@ from repro.core.store import (
 )
 from repro.core.superblock import build_suffix_array_superblock
 from repro.data.chunk_store import write_chunked_corpus
-from repro.runtime.fault import TransientFault, retry_step
 
 CFG = SAConfig(vocab_size=4, chars_per_word=2, key_words=2)
 
@@ -355,7 +359,7 @@ def test_retrying_backend_never_retries_corruption():
 
 
 # ---------------------------------------------------------------------------
-# retry_step taxonomy (runtime.fault)
+# retry_step taxonomy (core.integrity)
 # ---------------------------------------------------------------------------
 
 

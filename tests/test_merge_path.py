@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from repro.config import SAConfig, SuperblockConfig
 from repro.core import encoding
 from repro.core.oracle import doubling_sa_text, naive_sa_reads, naive_sa_text
-from repro.core.store import CorpusStore, pack_keys_np
+from repro.core.store import ChunkedFileBackend, CorpusStore, pack_keys_np
 from repro.core.superblock import build_suffix_array_superblock
 from repro.data.chunk_store import (
     ChunkedCorpusReader,
@@ -72,18 +72,38 @@ def test_property_merge_path_oracle_identical_text(n, seed):
             mp.suffix_array, _build(text, alg).suffix_array)
 
 
-def test_merge_path_chunked_backend_matches_memory():
+@pytest.mark.parametrize("verify", [None, True, False],
+                         ids=["array", "file-verified", "file-unverified"])
+def test_merge_path_chunked_backend_matches_memory(tmp_path, verify):
     """Both store backends, reads + text: identical SA and the streaming
-    residency bound still held by the tile frontier accounting."""
+    residency bound still held by the tile frontier accounting.  The
+    chunked build reads either the caller's array (serialized by the
+    build) or a corpus file opened with and without per-chunk crc checks:
+    checksumming is a pure observer, so all three give the oracle's SA."""
     rng = np.random.default_rng(7)
     reads = rng.integers(1, 5, size=(128, 16)).astype(np.int32)
     text = rng.integers(1, 5, size=(768,)).astype(np.int32)
-    for corpus, oracle in ((reads, naive_sa_reads(reads)),
-                           (text, doubling_sa_text(text))):
+    for name, corpus, oracle in (("reads", reads, naive_sa_reads(reads)),
+                                 ("text", text, doubling_sa_text(text))):
         budget = corpus.size * 4 // 4
         mem = _build(corpus, "merge_path", s=4)
-        ch = _build(corpus, "merge_path", s=4, store_backend="chunked",
-                    cache_budget_bytes=budget)
+        if verify is None:
+            ch = _build(corpus, "merge_path", s=4, store_backend="chunked",
+                        cache_budget_bytes=budget)
+        else:
+            path = str(tmp_path / f"{name}.sachunk")
+            # chunks of 512 B (reads) / 256 B (text): the LRU's half of the
+            # budget holds a few
+            write_chunked_corpus(corpus, path,
+                                 chunk_items=8 if corpus.ndim == 2 else 64)
+            backend = ChunkedFileBackend(path, CFG,
+                                         cache_budget_bytes=budget // 2,
+                                         verify=verify)
+            try:
+                ch = _build(backend, "merge_path", s=4,
+                            store_backend="chunked", cache_budget_bytes=budget)
+            finally:
+                backend.close()
         np.testing.assert_array_equal(mem.suffix_array, oracle)
         np.testing.assert_array_equal(ch.suffix_array, oracle)
         assert 0 < ch.footprint.peak_resident_bytes <= budget
@@ -165,11 +185,12 @@ def _roundtrips(corpus, alg, s):
     return res, res.stats["merge_fetch_rounds"]
 
 
-def test_merge_path_roundtrips_beat_kway_5x_random():
+@pytest.mark.parametrize("shape", [(48, 12), (576,)], ids=["reads", "text"])
+def test_merge_path_roundtrips_beat_kway_5x_random(shape):
     rng = np.random.default_rng(0)
-    reads = rng.integers(1, 5, size=(48, 12)).astype(np.int32)
-    mp, r_mp = _roundtrips(reads, "merge_path", 4)
-    kw_, r_kw = _roundtrips(reads, "kway", 4)
+    corpus = rng.integers(1, 5, size=shape).astype(np.int32)
+    mp, r_mp = _roundtrips(corpus, "merge_path", 4)
+    kw_, r_kw = _roundtrips(corpus, "kway", 4)
     np.testing.assert_array_equal(mp.suffix_array, kw_.suffix_array)
     assert r_kw >= 5 * r_mp, (r_mp, r_kw)
     # bytes stay comparable (the win is calls, not payload): within 2x

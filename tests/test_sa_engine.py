@@ -243,7 +243,10 @@ def test_index_save_open_round_trip(tmp_path, backend):
         assert re_ix.lcp is not None
         np.testing.assert_array_equal(re_ix.count(pats), want_counts)
         assert re_ix.align(pats[0]) == want_align
-        assert re_ix.stats()["backend"] == (
+        served = re_ix.stats()["backend"]
+        if served == "SanitizingBackend":  # REPRO_SANITIZE=1 wraps it
+            served = type(re_ix.store.backend.inner).__name__
+        assert served == (
             "ChunkedFileBackend" if backend == "chunked"
             else "InMemoryBackend")
 

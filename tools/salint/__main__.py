@@ -14,7 +14,7 @@ from typing import List, Optional
 from tools.salint.engine import run
 from tools.salint.rules import DEFAULT_RULES
 
-DEFAULT_PATHS = ["src", "tests", "benchmarks", "tools"]
+DEFAULT_PATHS = ["src", "tests", "tools"]
 
 
 def main(argv: Optional[List[str]] = None) -> int:

@@ -481,7 +481,7 @@ class Sal007DeprecatedWrapperCallers(Rule):
         "The raw-array wrappers rebuild a transient store per call — "
         "accounting-invisible and O(corpus) per query.  They exist only for "
         "external callers of the pre-store API and emit DeprecationWarning; "
-        "internal code (src/, benchmarks/, examples/) must use the "
+        "internal code (src/, examples/) must use the "
         "store-served search_store/count_store/locate_store or "
         "SuffixArrayIndex.  Their own tests keep exercising them until "
         "removal."
@@ -671,8 +671,8 @@ class Sal010WorkerDeviceAccounting(Rule):
                "mutate gated traffic counters (FetchStats accounting stays "
                "on the main thread)")
     rationale = (
-        "benchmarks/build.py gates the pipelined build on *traffic "
-        "equality*: the overlapped schedule must issue exactly the requests "
+        "tests/test_pipeline_exec.py gates the pipelined build on "
+        "*traffic equality*: the overlapped schedule must issue exactly the requests "
         "and bytes of the synchronous one, with accounting mutated only "
         "between pipeline points on the main thread.  A worker-context "
         "jnp/jax/kops call races the main thread's device stream (and can "
